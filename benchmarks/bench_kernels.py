@@ -29,7 +29,8 @@ def rows() -> List[BenchRow]:
     f(q, k, v)  # warm
     _, us = timed(lambda: jax.block_until_ready(f(q, k, v)))
     small = [x[:, :64] for x in (q, k, v)]
-    pall = ops.flash_attention(*small, causal=True, block_q=32, block_k=32)
+    pall = ops.flash_attention(*small, causal=True, block_q=32, block_k=32,
+                               interpret=True)
     want = jnp.swapaxes(ref.flash_attention_ref(
         *(jnp.swapaxes(x, 1, 2) for x in small), causal=True), 1, 2)
     err = float(jnp.max(jnp.abs(pall - want)))
@@ -47,7 +48,7 @@ def rows() -> List[BenchRow]:
     g(x, dt, a_log, B, C)
     _, us = timed(lambda: jax.block_until_ready(g(x, dt, a_log, B, C)[0]))
     y_p, f_p = ops.ssd_scan(x[:, :64], dt[:, :64], a_log, B[:, :64],
-                            C[:, :64], chunk=32)
+                            C[:, :64], chunk=32, interpret=True)
     y_r, f_r = ref.ssd_scan_ref(x[:, :64], dt[:, :64], a_log, B[:, :64],
                                 C[:, :64])
     err = float(jnp.max(jnp.abs(y_p - y_r)))
@@ -57,7 +58,7 @@ def rows() -> List[BenchRow]:
     # Algorithm 1 bucket map
     caps = jnp.asarray([715, 285], jnp.int32)      # 1.0 : 0.4
     hashes = jax.random.randint(KEY, (1 << 16,), 0, 1 << 30)
-    bk = ops.skewed_bucket(hashes, caps)
+    bk = ops.skewed_bucket(hashes, caps, interpret=True)
     br = ref.skewed_bucket_ref(hashes, caps)
     h = jax.jit(ref.skewed_bucket_ref)
     h(hashes, caps)

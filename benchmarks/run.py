@@ -203,6 +203,8 @@ def main() -> None:
                              f"{DEFAULT_THRESHOLD:g}x) — loaded CI runners "
                              "want more headroom than a quiet laptop")
     args = parser.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.check is not None:
         raise SystemExit(run_check(args.check, threshold=args.threshold))
     if args.json is not None and not args.json.endswith(".json"):

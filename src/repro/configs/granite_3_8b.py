@@ -1,5 +1,5 @@
 """granite-3-8b — 40L d_model=4096 32H (GQA kv=8) d_ff=12800 vocab=49155, GQA.
-[hf:ibm-granite/granite-3.0-2b-base; hf]
+[hf:ibm-granite/granite-3.0-8b-base; hf]
 """
 from repro.configs.base import ArchBundle, AttentionConfig, MeshConfig, ModelConfig
 

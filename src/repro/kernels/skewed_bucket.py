@@ -14,6 +14,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 
@@ -41,7 +42,10 @@ def skewed_bucket(hashes: jnp.ndarray, capacities: jnp.ndarray, *,
     if tp != t:
         hashes = jnp.pad(hashes, (0, tp - t))
     cum = jnp.cumsum(capacities.astype(jnp.int32))
-    total = int(capacities.sum()) if _is_static(capacities) else None
+    # a concrete vector (numpy, or a jax array closed over by a jit) folds
+    # its total into the kernel; only a tracer needs the two-operand path
+    total = (None if isinstance(capacities, jax.core.Tracer)
+             else int(np.asarray(capacities).sum()))
 
     if total is None:
         # traced capacities: fall back to a two-operand kernel with the
@@ -65,10 +69,6 @@ def skewed_bucket(hashes: jnp.ndarray, capacities: jnp.ndarray, *,
         interpret=interpret,
     )(src, cum)
     return out[:t]
-
-
-def _is_static(x) -> bool:
-    return not isinstance(x, jax.core.Tracer)
 
 
 def _round_up(x: int, m: int) -> int:

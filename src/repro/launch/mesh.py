@@ -1,4 +1,4 @@
-"""Production meshes.
+"""Production, host and 2x2 meshes.
 
 Defined as FUNCTIONS, not module-level constants, so importing this module
 never touches jax device state (the dry-run must set
@@ -7,15 +7,11 @@ never touches jax device state (the dry-run must set
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:                                    # jax >= 0.5
-    from jax.sharding import AxisType
 
-    def _axis_kw(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:                     # older jax: Auto is the only behavior
-    def _axis_kw(n: int) -> dict:
-        return {}
+def _mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -24,10 +20,16 @@ def make_production_mesh(*, multi_pod: bool = False):
     parallelism over DCN (HeMT-DP skews grain counts along it)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kw(len(shape)))
+    return _mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh with the production axis names — lets smoke tests run
     the exact same sharded code paths on CPU."""
-    return jax.make_mesh((1, 1), ("data", "model"), **_axis_kw(2))
+    return _mesh((1, 1), ("data", "model"))
+
+
+def make_2x2_mesh():
+    """The four chips of one v5e host as (data=2, model=2): the smallest
+    mesh on which both the batch rules and the model rules split."""
+    return _mesh((2, 2), ("data", "model"))

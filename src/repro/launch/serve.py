@@ -160,6 +160,8 @@ def main() -> None:
     if args.simulate:
         _simulate(args)
     else:
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
         if args.mode == "oracle":
             raise SystemExit("oracle mode exists only under --simulate")
         _demo(args)

@@ -11,13 +11,35 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+from typing import Sequence, Tuple
 
 import jax
 
-from repro.configs import ARCH_IDS, get_bundle, get_reduced
+from repro.configs import ARCH_IDS, ArchBundle, ModelConfig, get_bundle, get_reduced
 from repro.checkpoint import CheckpointManager
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime.hemt_driver import HeMTTrainer, SliceSpec
-from repro.runtime.train_loop import train_state_init
+from repro.runtime.train_loop import TrainState, train_state_init
+
+
+def build_trainer(cfg: ModelConfig, bundle: ArchBundle, *,
+                  speeds: Sequence[float], grain_batch: int,
+                  global_batch: int, seq_len: int, steps: int, lr: float,
+                  mode: str = "hemt", seed: int = 0,
+                  ) -> Tuple[HeMTTrainer, TrainState]:
+    """The trainer and its initial state: one slice per relative speed,
+    a warmup-cosine schedule sized to ``steps``."""
+    bundle = bundle.replace(
+        model=cfg,
+        train=dataclasses.replace(bundle.train, lr=lr,
+                                  total_steps=max(steps, 10),
+                                  warmup_steps=max(steps // 10, 1)))
+    slices = [SliceSpec(f"slice{i}", [(0.0, v)], grain_overhead=0.05)
+              for i, v in enumerate(speeds)]
+    trainer = HeMTTrainer(cfg, bundle, slices, grain_batch=grain_batch,
+                          global_batch=global_batch, seq_len=seq_len,
+                          mode=mode, seed=seed)
+    return trainer, train_state_init(jax.random.PRNGKey(seed), cfg, bundle)
 
 
 def main() -> None:
@@ -38,23 +60,13 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    cfg = get_reduced(args.arch)
-    bundle = get_bundle(args.arch)
-    bundle = bundle.replace(
-        model=cfg,
-        train=dataclasses.replace(bundle.train, lr=args.lr,
-                                  total_steps=max(args.steps, 10),
-                                  warmup_steps=max(args.steps // 10, 1)))
-
-    speeds = [float(s) for s in args.slices.split(",")]
-    slices = [SliceSpec(f"slice{i}", [(0.0, v)], grain_overhead=0.05)
-              for i, v in enumerate(speeds)]
-
-    trainer = HeMTTrainer(cfg, bundle, slices, grain_batch=args.grain_batch,
-                          global_batch=args.global_batch,
-                          seq_len=args.seq_len, mode=args.mode,
-                          seed=args.seed)
-    state = train_state_init(jax.random.PRNGKey(args.seed), cfg, bundle)
+    enable_compile_cache()
+    trainer, state = build_trainer(
+        get_reduced(args.arch), get_bundle(args.arch),
+        speeds=[float(s) for s in args.slices.split(",")],
+        grain_batch=args.grain_batch, global_batch=args.global_batch,
+        seq_len=args.seq_len, steps=args.steps, lr=args.lr, mode=args.mode,
+        seed=args.seed)
 
     mgr = CheckpointManager(args.ckpt) if args.ckpt else None
     start = 0

@@ -149,7 +149,10 @@ def make_grain_accumulate(cfg: ModelConfig, bundle: ArchBundle, *,
         out, _ = jax.lax.scan(body, acc, grains)
         return out
 
-    return jax.jit(grain_accumulate) if jit else grain_accumulate
+    # the incoming acc is a fresh zero tree the caller never reads again:
+    # donating it lets the fp32 grad accumulator update in place
+    return (jax.jit(grain_accumulate, donate_argnums=(1,)) if jit
+            else grain_accumulate)
 
 
 _GRAIN_ACC_CACHE: Dict[Any, Callable] = {}
@@ -195,4 +198,5 @@ def make_apply_step(cfg: ModelConfig, bundle: ArchBundle, *,
         metrics = {"loss": acc.loss_sum / denom, "grad_norm": gnorm, "lr": lr}
         return TrainState(params, opt, state.step + 1, ef), metrics
 
-    return jax.jit(apply_step) if jit else apply_step
+    # donating acc lets its fp32 grads back the new fp32 moments
+    return jax.jit(apply_step, donate_argnums=(1,)) if jit else apply_step

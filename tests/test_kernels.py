@@ -47,7 +47,8 @@ def test_flash_ops_wrapper_model_layout():
     q = jax.random.normal(ks[0], (2, 64, 4, 32))
     k = jax.random.normal(ks[1], (2, 64, 2, 32))
     v = jax.random.normal(ks[2], (2, 64, 2, 32))
-    out = ops.flash_attention(q, k, v, causal=True, block_q=32, block_k=32)
+    out = ops.flash_attention(q, k, v, causal=True, block_q=32, block_k=32,
+                              interpret=True)
     want = jnp.swapaxes(ref.flash_attention_ref(
         jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
         causal=True), 1, 2)
@@ -73,7 +74,8 @@ def test_ssd_scan_sweep(bsz, s, h, p, g, n, chunk, with_init):
     C = jax.random.normal(ks[3], (bsz, s, g, n)) * 0.3
     init = (jax.random.normal(ks[4], (bsz, h, p, n)) * 0.1
             if with_init else None)
-    y, f = ops.ssd_scan(x, dt, a_log, B, C, chunk=chunk, init_state=init)
+    y, f = ops.ssd_scan(x, dt, a_log, B, C, chunk=chunk, init_state=init,
+                        interpret=True)
     yr, fr = ref.ssd_scan_ref(x, dt, a_log, B, C, init_state=init)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=2e-3)
     np.testing.assert_allclose(np.asarray(f), np.asarray(fr), atol=2e-3)
@@ -89,7 +91,8 @@ def test_ssd_scan_sweep(bsz, s, h, p, g, n, chunk, with_init):
 def test_skewed_bucket_sweep(weights, t):
     caps = integer_capacities(weights, resolution=997)
     hashes = jax.random.randint(KEY, (t,), 0, 2**30)
-    got = ops.skewed_bucket(hashes, jnp.asarray(caps, jnp.int32))
+    got = ops.skewed_bucket(hashes, jnp.asarray(caps, jnp.int32),
+                            interpret=True)
     want_ref = ref.skewed_bucket_ref(hashes, jnp.asarray(caps, jnp.int32))
     want_np = bucket_of(np.asarray(hashes), caps)
     assert (np.asarray(got) == np.asarray(want_ref)).all()
