@@ -243,11 +243,13 @@ def main(argv=None) -> None:
         raise SystemExit(f"no TPU: JAX found {dev}")
     if dev["count"] < args.chips:
         raise SystemExit(f"--chips {args.chips} but JAX found {dev}")
-    _log(phase="device", **dev)
-
+    # import the package before the first line of output, so that a copy of
+    # this script without the repository prints nothing
     sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                     "src"))
     from repro.launch.compile_cache import enable_compile_cache
+    _log(phase="device", **dev)
+
     enable_compile_cache()
     cfg, bundle = smoke_config()
     _log(phase="config", arch=ARCH, n_layers=cfg.n_layers,
