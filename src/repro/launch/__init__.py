@@ -1,1 +1,1 @@
-"""Launcher: production meshes, multi-pod dry-run, roofline, train/serve CLIs."""
+"""Launcher: production meshes, multi-pod dry-run, train/serve CLIs."""

@@ -11,8 +11,8 @@ Per cell this driver:
   2. builds ShapeDtypeStruct stand-ins (launch.specs.input_specs),
   3. builds shardings (runtime.sharding) with divisibility fallbacks,
   4. jit(...).lower(...).compile()  — failure = a sharding bug in this repo,
-  5. records memory_analysis / cost_analysis / loop-adjusted HLO cost +
-     roofline terms into artifacts/dryrun/<arch>__<shape>__<mesh>.json.
+  5. records lowering and compile time, memory_analysis and XLA's
+     cost_analysis into artifacts/dryrun/<arch>__<shape>__<mesh>.json.
 
 Usage:
   python -m repro.launch.dryrun                         # full sweep
@@ -30,9 +30,7 @@ import jax.numpy as jnp
 from repro.configs import ARCH_IDS, get_bundle
 from repro.configs.shapes import ALL_SHAPES, SHAPES, shape_skip_reason
 from repro.launch import specs as specs_mod
-from repro.launch.hlo_cost import parse_hlo
 from repro.launch.mesh import make_production_mesh
-from repro.launch.roofline import compute_roofline, improvement_hint
 from repro.models.model import decode_step, prefill
 from repro.runtime.sharding import (
     ShardingReport, batch_shardings, cache_shardings,
@@ -76,7 +74,6 @@ def lower_cell(arch: str, shape_name: str, mesh_kind: str,
         return None, None, {"skip": skip}
     mesh = make_production_mesh(**MESHES[mesh_kind])
     n_chips = mesh.devices.size
-    pod_size = 256 if mesh_kind == "multi" else None
     report = ShardingReport()
     cell = specs_mod.input_specs(cfg, bundle, shape)
 
@@ -125,8 +122,7 @@ def lower_cell(arch: str, shape_name: str, mesh_kind: str,
                           donate_argnums=(1,)).lower(*args)
 
     ctx = {"bundle": bundle, "cfg": cfg, "shape": shape, "mesh": mesh,
-           "n_chips": n_chips, "pod_size": pod_size,
-           "fallbacks": report.fallbacks}
+           "n_chips": n_chips, "fallbacks": report.fallbacks}
     return lowered, ctx
 
 
@@ -151,13 +147,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
 
         ma = compiled.memory_analysis()
         ca = compiled.cost_analysis() or {}
-        cost = parse_hlo(compiled.as_text(), pod_size=ctx["pod_size"])
-        ici_bytes = cost.collective_operand_bytes - cost.dcn_operand_bytes
-        roof = compute_roofline(
-            ctx["cfg"], ctx["shape"], n_chips=ctx["n_chips"],
-            hlo_flops=cost.flops, hlo_bytes=cost.bytes_accessed,
-            ici_bytes=ici_bytes, dcn_bytes=cost.dcn_operand_bytes)
-
         rec.update({
             "status": "ok",
             "n_chips": ctx["n_chips"],
@@ -165,13 +154,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
             "memory_analysis": _mem_dict(ma),
             "xla_cost_analysis": {k: float(v) for k, v in ca.items()
                                   if isinstance(v, (int, float))},
-            "hlo_cost": cost.summary(),
-            "collectives": [
-                {"kind": c.kind, "bytes": c.operand_bytes,
-                 "group": c.group_size, "dcn": c.pod_crossing,
-                 "count": c.count} for c in cost.collectives],
-            "roofline": roof.as_dict(),
-            "hint": improvement_hint(roof),
             "sharding_fallbacks": ctx["fallbacks"],
         })
     except Exception as e:  # noqa: BLE001 — recorded, sweep continues
@@ -191,13 +173,10 @@ def _write(rec: Dict[str, Any], out_dir: str) -> Dict[str, Any]:
     status = rec["status"]
     line = f"{rec['arch']:24s} {rec['shape']:12s} {rec['mesh']:7s} {status:8s}"
     if status == "ok":
-        r = rec["roofline"]
-        mb = rec["memory_analysis"].get("temp_size_in_bytes", 0) / 1e9
+        ma = rec["memory_analysis"]
         line += (f" compile={rec['compile_s']:6.1f}s"
-                 f" args={rec['memory_analysis'].get('argument_size_in_bytes', 0)/1e9:7.2f}GB"
-                 f" temp={mb:7.2f}GB"
-                 f" c/m/coll={r['compute_s']:.3f}/{r['memory_s']:.3f}/"
-                 f"{r['collective_s']:.3f}s -> {r['bottleneck']}")
+                 f" args={ma.get('argument_size_in_bytes', 0) / 1e9:7.2f}GB"
+                 f" temp={ma.get('temp_size_in_bytes', 0) / 1e9:7.2f}GB")
     elif status == "skipped":
         line += f" ({rec['reason'][:60]})"
     else:

@@ -18,6 +18,7 @@ import jax
 from repro.configs import ARCH_IDS, ArchBundle, ModelConfig, get_bundle, get_reduced
 from repro.checkpoint import CheckpointManager
 from repro.launch.compile_cache import enable_compile_cache
+from repro.runtime import telemetry
 from repro.runtime.hemt_driver import HeMTTrainer, SliceSpec
 from repro.runtime.train_loop import TrainState, train_state_init
 
@@ -40,6 +41,16 @@ def build_trainer(cfg: ModelConfig, bundle: ArchBundle, *,
                           global_batch=global_batch, seq_len=seq_len,
                           mode=mode, seed=seed)
     return trainer, train_state_init(jax.random.PRNGKey(seed), cfg, bundle)
+
+
+def step_times(step: int) -> Tuple[float, float]:
+    """Measured milliseconds of training step ``step``, from its spans: the
+    whole step, and the host's part of it (the step less its wait for the
+    device)."""
+    recs = [r for r in telemetry.records()[-64:] if r.step == step]
+    ms = lambda name: sum(r.t1_ns - r.t0_ns for r in recs if r.name == name) / 1e6
+    step_ms = ms("repro.train.step")
+    return step_ms, step_ms - ms("repro.train.wait")
 
 
 def main() -> None:
@@ -78,10 +89,12 @@ def main() -> None:
 
     for _ in range(args.steps - start):
         state, rep = trainer.run_step(state)
+        step_ms, host_ms = step_times(rep.step)
         print(json.dumps({
             "step": rep.step, "loss": round(rep.loss, 4),
             "makespan_s": round(rep.makespan, 2),
             "idle_s": round(rep.idle_time, 2),
+            "step_ms": round(step_ms, 3), "host_ms": round(host_ms, 3),
             "grains": rep.grain_counts}), flush=True)
         if mgr is not None and (rep.step + 1) % args.ckpt_every == 0:
             mgr.save_async(rep.step + 1, state)
@@ -90,6 +103,7 @@ def main() -> None:
         mgr.save(args.steps, state)
     print(f"total fleet time {trainer.total_time():.1f}s  "
           f"mean barrier idle {trainer.mean_idle():.2f}s  mode={args.mode}")
+    print(json.dumps({"telemetry": telemetry.summary()}), flush=True)
 
 
 if __name__ == "__main__":

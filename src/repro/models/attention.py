@@ -47,14 +47,15 @@ def dot_product_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     b, sq, hq, dh = q.shape
     hkv = k.shape[2]
     group = hq // hkv
-    qg = q.reshape(b, sq, hkv, group, dh)
-    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale
-    if bias is not None:
-        logits = logits + bias[:, None, None, :, :]
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(v.dtype), v)
-    return out.reshape(b, sq, hq, dh)
+    with jax.named_scope("attention_core"):
+        qg = q.reshape(b, sq, hkv, group, dh)
+        logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
+                            k.astype(jnp.float32)) * scale
+        if bias is not None:
+            logits = logits + bias[:, None, None, :, :]
+        probs = jax.nn.softmax(logits, axis=-1)
+        out = jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(v.dtype), v)
+        return out.reshape(b, sq, hq, dh)
 
 
 def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -128,7 +129,8 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
     q_block = jax.checkpoint(
         q_block, policy=jax.checkpoint_policies.nothing_saveable)
-    out = jax.lax.map(q_block, (jnp.arange(nq), qb))      # (nq,B,Hkv,g,bq,D)
+    with jax.named_scope("attention_core"):
+        out = jax.lax.map(q_block, (jnp.arange(nq), qb))  # (nq,B,Hkv,g,bq,D)
     out = out.transpose(1, 0, 4, 2, 3, 5).reshape(b, sq_p, hq, d)
     return out[:, :sq].astype(q.dtype)
 
@@ -266,8 +268,9 @@ def attention_decode_step(params: Params, x: jnp.ndarray, cache: Dict[str, jnp.n
     v_new = (x @ params["wv"]).reshape(b, 1, hkv, dh)
 
     slot = jnp.mod(cache_len, cap)
-    k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, slot, axis=1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, slot, axis=1)
+    with jax.named_scope("kv_cache_update"):
+        k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, slot, axis=1)
+        v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, slot, axis=1)
 
     # Ring buffer: absolute position stored at slot i is the largest p <= L
     # with p % cap == i, i.e. abs(i) = L - ((L - i) mod cap); L = cache_len

@@ -186,11 +186,12 @@ def chunked_softmax_xent(x: jnp.ndarray, table: jnp.ndarray,
 
     step = jax.checkpoint(step, policy=jax.checkpoint_policies.nothing_saveable)
     b, s = labels.shape
-    init = (jnp.full((b, s), NEG_INF, jnp.float32),
-            jnp.zeros((b, s), jnp.float32), jnp.zeros((b, s), jnp.float32))
-    (m, l, t), _ = jax.lax.scan(step, init, (jnp.arange(nc), tchunks))
-    lse = jnp.log(l) + m
-    return lse - t
+    with jax.named_scope("cross_entropy"):
+        init = (jnp.full((b, s), NEG_INF, jnp.float32),
+                jnp.zeros((b, s), jnp.float32), jnp.zeros((b, s), jnp.float32))
+        (m, l, t), _ = jax.lax.scan(step, init, (jnp.arange(nc), tchunks))
+        lse = jnp.log(l) + m
+        return lse - t
 
 
 def loss_fn(params: Pytree, batch: Dict[str, jnp.ndarray], cfg: ModelConfig, *,
@@ -299,9 +300,10 @@ def decode_step(params: Pytree, state: Pytree, token: jnp.ndarray,
 
     x, new_cache = transformer.stack_decode_step(
         params["stack"], state["cache"], x, state["length"], cfg, enc_out=enc_out)
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    head = params["unembed"] if "unembed" in params else params["embed"]
-    logits = mask_pad_logits(unembed(head, x)[:, 0, :], cfg)
+    with jax.named_scope("decode_sample"):
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        head = params["unembed"] if "unembed" in params else params["embed"]
+        logits = mask_pad_logits(unembed(head, x)[:, 0, :], cfg)
     return logits, {"cache": new_cache, "length": state["length"] + 1}
 
 

@@ -92,56 +92,59 @@ def moe_apply(params: Params, x: jnp.ndarray, cfg: MoEConfig, act: str = "silu",
     aux = e * jnp.sum(me * ce) * cfg.aux_loss_weight
 
     # ---- sort-based grouped dispatch -------------------------------------
-    # flatten expert choices per batch row: (B, S*k)
-    exp_flat = top_i.reshape(b, s * k)
-    w_flat = top_w.reshape(b, s * k)
-    tok_flat = jnp.broadcast_to(jnp.arange(s)[:, None], (s, k)).reshape(s * k)
-    tok_flat = jnp.broadcast_to(tok_flat, (b, s * k))
+    with jax.named_scope("moe_dispatch"):
+        # flatten expert choices per batch row: (B, S*k)
+        exp_flat = top_i.reshape(b, s * k)
+        w_flat = top_w.reshape(b, s * k)
+        tok_flat = jnp.broadcast_to(jnp.arange(s)[:, None], (s, k)).reshape(s * k)
+        tok_flat = jnp.broadcast_to(tok_flat, (b, s * k))
 
-    order = jnp.argsort(exp_flat, axis=-1, stable=True)          # (B, S*k)
-    exp_s = jnp.take_along_axis(exp_flat, order, -1)
-    tok_s = jnp.take_along_axis(tok_flat, order, -1)
-    w_s = jnp.take_along_axis(w_flat, order, -1)
+        order = jnp.argsort(exp_flat, axis=-1, stable=True)          # (B, S*k)
+        exp_s = jnp.take_along_axis(exp_flat, order, -1)
+        tok_s = jnp.take_along_axis(tok_flat, order, -1)
+        w_s = jnp.take_along_axis(w_flat, order, -1)
 
-    # position within its expert run: exp_s is sorted, so the run start of
-    # expert e is searchsorted(exp_s, e) — O(S*k*logE) and (B, E) memory
-    # instead of the (B, S*k, E) cumsum tensor (16.8 GB/layer for dbrx)
-    starts = jax.vmap(
-        lambda row: jnp.searchsorted(row, jnp.arange(e), side="left"))(exp_s)
-    pos_in_exp = jnp.arange(s * k)[None, :] - jnp.take_along_axis(
-        starts, exp_s, axis=1)                                   # (B, S*k)
+        # position within its expert run: exp_s is sorted, so the run start of
+        # expert e is searchsorted(exp_s, e) — O(S*k*logE) and (B, E) memory
+        # instead of the (B, S*k, E) cumsum tensor (16.8 GB/layer for dbrx)
+        starts = jax.vmap(
+            lambda row: jnp.searchsorted(row, jnp.arange(e), side="left"))(exp_s)
+        pos_in_exp = jnp.arange(s * k)[None, :] - jnp.take_along_axis(
+            starts, exp_s, axis=1)                                   # (B, S*k)
 
-    keep = pos_in_exp < caps[exp_s]
-    slot = jnp.where(keep, exp_s * cap_buf + jnp.minimum(pos_in_exp, cap_buf - 1),
-                     e * cap_buf)                                # drop slot
+        keep = pos_in_exp < caps[exp_s]
+        slot = jnp.where(keep, exp_s * cap_buf + jnp.minimum(pos_in_exp, cap_buf - 1),
+                         e * cap_buf)                                # drop slot
 
-    # scatter tokens into (B, E*cap+1, D) then drop the overflow row
-    src = jnp.take_along_axis(x, tok_s[..., None], axis=1)       # (B, S*k, D)
-    buf = jnp.zeros((b, e * cap_buf + 1, d), x.dtype)
-    buf = jax.vmap(lambda bf, sl, sr: bf.at[sl].set(sr))(buf, slot, src)
-    buf = buf[:, : e * cap_buf].reshape(b, e, cap_buf, d)
-    if constrain is not None:
-        buf = constrain(buf, kind="moe_buffer")   # the EP all-to-all
+        # scatter tokens into (B, E*cap+1, D) then drop the overflow row
+        src = jnp.take_along_axis(x, tok_s[..., None], axis=1)       # (B, S*k, D)
+        buf = jnp.zeros((b, e * cap_buf + 1, d), x.dtype)
+        buf = jax.vmap(lambda bf, sl, sr: bf.at[sl].set(sr))(buf, slot, src)
+        buf = buf[:, : e * cap_buf].reshape(b, e, cap_buf, d)
+        if constrain is not None:
+            buf = constrain(buf, kind="moe_buffer")   # the EP all-to-all
 
     # ---- expert FFN -------------------------------------------------------
-    activation = jax.nn.silu if act == "silu" else jax.nn.gelu
-    up = jnp.einsum("becd,edf->becf", buf, params["w_up"])
-    if "w_gate" in params:
-        gate = jnp.einsum("becd,edf->becf", buf, params["w_gate"])
-        up = activation(gate) * up
-    else:
-        up = activation(up)
-    out_buf = jnp.einsum("becf,efd->becd", up, params["w_down"])
-    if constrain is not None:
-        out_buf = constrain(out_buf, kind="moe_buffer")
-    out_buf = out_buf.reshape(b, e * cap_buf, d)
-    out_buf = jnp.concatenate([out_buf, jnp.zeros((b, 1, d), x.dtype)], axis=1)
+    with jax.named_scope("moe_experts"):
+        activation = jax.nn.silu if act == "silu" else jax.nn.gelu
+        up = jnp.einsum("becd,edf->becf", buf, params["w_up"])
+        if "w_gate" in params:
+            gate = jnp.einsum("becd,edf->becf", buf, params["w_gate"])
+            up = activation(gate) * up
+        else:
+            up = activation(up)
+        out_buf = jnp.einsum("becf,efd->becd", up, params["w_down"])
+        if constrain is not None:
+            out_buf = constrain(out_buf, kind="moe_buffer")
+        out_buf = out_buf.reshape(b, e * cap_buf, d)
+        out_buf = jnp.concatenate([out_buf, jnp.zeros((b, 1, d), x.dtype)], axis=1)
 
     # ---- combine -----------------------------------------------------------
-    gathered = jax.vmap(lambda bf, sl: bf[sl])(out_buf, slot)    # (B, S*k, D)
-    gathered = gathered * (w_s * keep)[..., None].astype(x.dtype)
-    out = jnp.zeros((b, s, d), x.dtype)
-    out = jax.vmap(lambda o, t, g: o.at[t].add(g))(out, tok_s, gathered)
+    with jax.named_scope("moe_combine"):
+        gathered = jax.vmap(lambda bf, sl: bf[sl])(out_buf, slot)    # (B, S*k, D)
+        gathered = gathered * (w_s * keep)[..., None].astype(x.dtype)
+        out = jnp.zeros((b, s, d), x.dtype)
+        out = jax.vmap(lambda o, t, g: o.at[t].add(g))(out, tok_s, gathered)
     return out, aux
 
 

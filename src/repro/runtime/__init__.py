@@ -1,4 +1,7 @@
 """Distributed runtime: sharding rules, train/serve loops, FT, elasticity."""
+# imported first so that its compile listener sees every program the
+# runtime compiles
+from repro.runtime import telemetry  # noqa: F401
 from repro.runtime.sharding import (  # noqa: F401
     axis_rules, batch_shardings, cache_shardings, param_shardings,
     shardings_for, train_state_shardings,

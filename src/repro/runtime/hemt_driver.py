@@ -40,6 +40,8 @@ from repro.core.planner import GrainPlanner
 from repro.core.simulator import SimNode, SimTask, run_pull_stage, run_static_stage
 from repro.data.grains import GrainSource, plan_grain_ranges
 from repro.data.pipeline import SyntheticCorpus
+from repro.runtime import telemetry
+from repro.runtime.telemetry import span
 from repro.runtime.train_loop import (
     TrainState, grain_acc_init, grain_accumulate_cached, make_apply_step,
 )
@@ -150,7 +152,7 @@ class HeMTTrainer:
 
     # ------------------------------------------------------------------
     def _execute_math(self, state: TrainState, counts: Dict[str, int],
-                      ) -> Tuple[TrainState, Dict]:
+                      step: int) -> Tuple[TrainState, Dict]:
         """Fold one step's grains and apply the update.
 
         Real math: every grain's gradient accumulates (order-independent).
@@ -161,31 +163,46 @@ class HeMTTrainer:
         jnp.asarray snapshots it for the device, and the step blocks on
         its own loss before the next step refills it.
         """
-        assignment = plan_grain_ranges(
-            int(state.step), self.global_batch, self.grain_batch,
-            list(counts), list(counts.values()))
-        block = self.source.load_stacked(
-            [g for grains in assignment.per_slice.values() for g in grains])
-        stacked = {k: jnp.asarray(v) for k, v in block.items()}
-        acc = grain_acc_init(state.params)
-        acc = self.grain_accumulate(state.params, acc, stacked)
-        self.grain_dispatches += 1
-        return self.apply_step(state, acc, jnp.asarray(self.n_grains))
+        with span("repro.train.stage"):
+            assignment = plan_grain_ranges(
+                step, self.global_batch, self.grain_batch,
+                list(counts), list(counts.values()))
+            block = self.source.load_stacked(
+                [g for grains in assignment.per_slice.values() for g in grains])
+            with span("repro.train.put"):
+                stacked = {k: jnp.asarray(v) for k, v in block.items()}
+        with span("repro.train.acc_init"):
+            acc = grain_acc_init(state.params)
+        with span("repro.train.dispatch"):
+            acc = self.grain_accumulate(state.params, acc, stacked)
+            self.grain_dispatches += 1
+            out = self.apply_step(state, acc, jnp.asarray(self.n_grains))
+        telemetry.count("train.grains", self.n_grains)
+        telemetry.count("train.staged_bytes",
+                        sum(int(v.nbytes) for v in block.values()))
+        return out
 
     def run_step(self, state: TrainState) -> Tuple[TrainState, StepReport]:
         step = int(state.step)
-        counts, elapsed, makespan, idle, steals = self._schedule(step)
-        state, metrics = self._execute_math(state, counts)
+        with span("repro.train.step", step=step):
+            with span("repro.train.schedule"):
+                counts, elapsed, makespan, idle, steals = self._schedule(step)
+            state, metrics = self._execute_math(state, counts, step)
 
-        # feed the estimator with the *virtual* observations (work, time)
-        self.planner.observe_step(
-            {name: {"grains": counts[name], "elapsed": max(elapsed[name], 1e-9)}
-             for name in counts if counts[name] > 0})
+            # feed the estimator with the *virtual* observations (work, time)
+            with span("repro.train.observe"):
+                self.planner.observe_step(
+                    {name: {"grains": counts[name],
+                            "elapsed": max(elapsed[name], 1e-9)}
+                     for name in counts if counts[name] > 0})
 
-        self._clock += makespan
-        rep = StepReport(step, self.mode, counts, elapsed, makespan, idle,
-                         float(metrics["loss"]), steals)
-        self.reports.append(rep)
+            self._clock += makespan
+            with span("repro.train.wait"):
+                loss = float(metrics["loss"])
+            rep = StepReport(step, self.mode, counts, elapsed, makespan, idle,
+                             loss, steals)
+            self.reports.append(rep)
+        telemetry.count("train.steps")
         return state, rep
 
     def run_window(self, state: TrainState, n_steps: int, *,
@@ -264,7 +281,8 @@ class HeMTTrainer:
             # *eaten* (the step's gradients all accumulate anyway), never
             # folded into the next barrier's quantum budget
             fold_lost=False)
-        result = ResidentCalendar(nodes, faults=trace).run([job])
+        with span("repro.train.schedule"):
+            result = ResidentCalendar(nodes, faults=trace).run([job])
         outcome = result.outcomes["window"]
         clock0 = self._clock
         dead_all: List[str] = []
@@ -274,9 +292,13 @@ class HeMTTrainer:
             elapsed = {nm: summ.node_finish[nm] - summ.start
                        for nm in counts}
             step = int(state.step)
-            state, metrics = self._execute_math(state, counts)
+            with span("repro.train.step", step=step):
+                state, metrics = self._execute_math(state, counts, step)
+                with span("repro.train.wait"):
+                    loss = float(metrics["loss"])
+            telemetry.count("train.steps")
             rep = StepReport(step, self.mode, counts, elapsed, summ.span,
-                             summ.idle_time, float(metrics["loss"]), 0)
+                             summ.idle_time, loss, 0)
             self.reports.append(rep)
             self._clock = clock0 + summ.completion
             if monitor is not None:

@@ -12,7 +12,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
-
+import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
@@ -33,10 +33,10 @@ def make_serve_step(cfg: ModelConfig, *, sample: str = "greedy",
                    enc_out: Optional[jnp.ndarray] = None):
         logits, new_state = decode_step(params, state, tokens, cfg,
                                         enc_out=enc_out)
-        if sample == "greedy":
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
+        if sample != "greedy":
             raise ValueError(sample)
+        with jax.named_scope("decode_sample"):
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return nxt, logits, new_state
 
     return serve_step

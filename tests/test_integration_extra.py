@@ -1,6 +1,7 @@
 """Deeper integration: elasticity mid-training, burstable fleets,
 HeMT-EP capacity routing, cluster-state offers."""
 import dataclasses
+import json
 
 import jax
 import jax.numpy as jnp
@@ -158,6 +159,11 @@ def test_train_cli_smoke(tmp_path, capsys):
         sys.argv = argv
     out = capsys.readouterr().out
     assert out.count('"loss"') == 3
+    # each step line carries its measured times; the last line the spans
+    lines = [json.loads(x) for x in out.splitlines() if x.startswith("{")]
+    assert all(0 < r["host_ms"] <= r["step_ms"] for r in lines[:3])
+    spans = lines[-1]["telemetry"]["spans"]
+    assert spans["repro.train.step"]["count"] >= 3
     # a checkpoint was committed and resume works
     sys.argv = ["train", "--steps", "4", "--global-batch", "8",
                 "--grain-batch", "2", "--seq-len", "16",

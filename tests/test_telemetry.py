@@ -1,0 +1,126 @@
+"""The program's host spans and counters (``runtime/telemetry.py``), and
+the spans of a HeMT-DP training step."""
+import dataclasses
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.configs import ArchBundle, TrainConfig, get_reduced
+from repro.runtime import telemetry
+from repro.runtime.hemt_driver import HeMTTrainer, SliceSpec
+from repro.runtime.train_loop import train_state_init
+
+STEP_CHILDREN = {"repro.train.schedule", "repro.train.stage", "repro.train.acc_init",
+                 "repro.train.dispatch", "repro.train.observe", "repro.train.wait"}
+
+
+@pytest.fixture(autouse=True)
+def fresh():
+    telemetry.reset()
+    yield
+    telemetry.reset()
+
+
+def by_name(recs, name):
+    return [r for r in recs if r.name == name]
+
+
+def test_nesting_gives_parents_and_self_times():
+    with telemetry.span("repro.t.outer", step=7):
+        with telemetry.span("repro.t.inner"):
+            time.sleep(0.02)
+        time.sleep(0.01)
+    inner, outer = telemetry.records()
+    assert (inner.name, inner.parent, inner.step) == ("repro.t.inner", "repro.t.outer", 7)
+    assert (outer.name, outer.parent, outer.step) == ("repro.t.outer", None, 7)
+    assert outer.t0_ns <= inner.t0_ns < inner.t1_ns <= outer.t1_ns
+    s = telemetry.summary()["spans"]
+    assert s["repro.t.outer"]["count"] == 1
+    assert s["repro.t.outer"]["total_ms"] >= 30.0
+    assert s["repro.t.inner"]["self_ms"] == pytest.approx(s["repro.t.inner"]["total_ms"])
+    outer_ms = (outer.t1_ns - outer.t0_ns) / 1e6
+    inner_ms = (inner.t1_ns - inner.t0_ns) / 1e6
+    assert s["repro.t.outer"]["self_ms"] == pytest.approx(outer_ms - inner_ms)
+    assert 10.0 <= s["repro.t.outer"]["self_ms"] < 20.0 <= inner_ms
+
+
+def test_self_time_counts_overlapping_children_once():
+    assert telemetry.covered_ns([(0, 10), (5, 12), (20, 25), (21, 22)]) == 17
+    assert telemetry.covered_ns([]) == 0
+
+
+def test_a_span_is_recorded_when_its_block_raises():
+    with pytest.raises(ValueError):
+        with telemetry.span("repro.t.fails"):
+            raise ValueError("boom")
+    with telemetry.span("repro.t.after"):
+        pass
+    fails, after = telemetry.records()
+    assert fails.name == "repro.t.fails" and after.parent is None
+
+
+def test_the_buffer_stays_bounded():
+    for _ in range(telemetry.MAXLEN + 100):
+        with telemetry.span("repro.t.many"):
+            pass
+    recs = telemetry.records()
+    assert len(recs) == telemetry.MAXLEN
+    assert telemetry.summary()["spans"]["repro.t.many"]["count"] == telemetry.MAXLEN
+
+
+def test_counters():
+    telemetry.count("t.a")
+    telemetry.count("t.a", 4)
+    telemetry.count("t.b", 2)
+    c = telemetry.summary()["counters"]
+    assert c["t.a"] == 5 and c["t.b"] == 2
+    telemetry.reset()
+    assert telemetry.summary() == {"spans": {}, "counters": {}}
+
+
+def test_a_fresh_jit_compiles_once():
+    f = jax.jit(lambda x: jnp.sin(x) * 3.0 + 1.0)
+    x, y = jnp.arange(8.0), jnp.ones(8)
+    telemetry.reset()
+    with telemetry.span("repro.t.call", step=0):
+        f(x).block_until_ready()
+    backend = by_name(telemetry.records(), "repro.compile.backend")
+    assert len(backend) == 1
+    assert (backend[0].parent, backend[0].step) == ("repro.t.call", 0)
+    assert backend[0].t0_ns < backend[0].t1_ns
+    assert telemetry.summary()["counters"]["compile.backend"] == 1
+    telemetry.reset()
+    f(y).block_until_ready()
+    assert not [r for r in telemetry.records() if r.name.startswith("repro.compile.")]
+    assert "compile.backend" not in telemetry.summary()["counters"]
+
+
+def test_run_step_spans_and_no_recompiles_in_steady_state():
+    cfg = dataclasses.replace(get_reduced("granite-3-8b"), n_layers=2)
+    bundle = ArchBundle(model=cfg, train=TrainConfig(lr=1e-3, warmup_steps=2,
+                                                     total_steps=50))
+    tr = HeMTTrainer(cfg, bundle, [SliceSpec("s0"), SliceSpec("s1", [(0.0, 0.5)])],
+                     grain_batch=2, global_batch=8, seq_len=16, mode="hemt")
+    state = train_state_init(jax.random.PRNGKey(0), cfg, bundle)
+    for _ in range(3):
+        state, _ = tr.run_step(state)
+    recs = telemetry.records()
+    steps = by_name(recs, "repro.train.step")
+    assert [r.step for r in steps] == [0, 1, 2]
+    for st in steps:
+        assert st.parent is None
+        kids = [r for r in recs if r.parent == "repro.train.step" and r.step == st.step]
+        assert {r.name for r in kids} == STEP_CHILDREN
+        assert all(st.t0_ns <= r.t0_ns and r.t1_ns <= st.t1_ns for r in kids)
+        put = [r for r in by_name(recs, "repro.train.put") if r.step == st.step]
+        assert len(put) == 1 and put[0].parent == "repro.train.stage"
+    # every program is lowered and compiled in step 0; later steps only
+    # trace the eager zeros of the accumulator again
+    compiles = [r for r in recs if r.name.startswith("repro.compile.") and r.step is not None]
+    assert {r.step for r in compiles if r.name != "repro.compile.trace"} == {0}
+    assert {r.parent for r in compiles if r.step > 0} <= {"repro.train.acc_init"}
+    c = telemetry.summary()["counters"]
+    assert c["train.steps"] == 3 and c["train.grains"] == 3 * 4
+    assert c["train.staged_bytes"] == 3 * 2 * 8 * 16 * 4     # tokens + labels, int32
