@@ -11,6 +11,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.configs.base import AttentionConfig
 from repro.models.layers import Params, _dense_init, apply_rope
@@ -219,9 +220,12 @@ def attention_prefill(params: Params, x: jnp.ndarray, cfg: AttentionConfig,
     src = (s - 1) - jnp.mod((s - 1) - idx, cap)
     valid = src >= 0
     srcc = jnp.clip(src, 0, s - 1)
-    gk = jnp.where(valid[None, :, None, None], jnp.take(k, srcc, axis=1), 0)
-    gv = jnp.where(valid[None, :, None, None], jnp.take(v, srcc, axis=1), 0)
-    return out, {"k": gk.astype(x.dtype), "v": gv.astype(x.dtype)}
+
+    def ring(t):                                          # -> (B, Hkv, cap, Dh)
+        t = jnp.where(valid[None, :, None, None], jnp.take(t, srcc, axis=1), 0)
+        return t.transpose(0, 2, 1, 3).astype(x.dtype)
+
+    return out, {"k": ring(k), "v": ring(v)}
 
 
 # --------------------------------------------------------------------------
@@ -230,47 +234,51 @@ def attention_prefill(params: Params, x: jnp.ndarray, cfg: AttentionConfig,
 
 def init_kv_cache(batch: int, max_len: int, cfg: AttentionConfig,
                   dtype=jnp.bfloat16) -> Dict[str, jnp.ndarray]:
-    shape = (batch, max_len, cfg.n_kv_heads, cfg.head_dim)
+    """Head-major K and V, (B, Hkv, max_len, Dh): the layout both decode
+    dots read, so a layer's cache is used where it lies."""
+    shape = (batch, cfg.n_kv_heads, max_len, cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def attention_decode_step(params: Params, x: jnp.ndarray, cache: Dict[str, jnp.ndarray],
-                          cache_len: jnp.ndarray, cfg: AttentionConfig, *,
+                          layer: jnp.ndarray, cache_len: jnp.ndarray,
+                          cfg: AttentionConfig, *,
                           window_override: Optional[int] = None,
-                          kv_source: Optional[jnp.ndarray] = None,
                           ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """One-token decode. x: (B, 1, D); cache_len: scalar int32 (current length).
 
-    The KV cache is a ring buffer of size cache['k'].shape[1]; for sliding
-    window layers the cache is allocated at window size so wrap-around
-    implements eviction for free.
+    ``cache`` is the stacked cache of every layer of this kind, each of
+    {"k", "v"} (n_layers, B, Hkv, cap, Dh); this step writes its one token
+    into row ``layer`` in place and reads that row where it lies. Each row
+    is a ring buffer of ``cap`` slots; sliding-window layers are allocated
+    at window size, so wrap-around implements eviction for free.
     """
     b, one, _ = x.shape
     assert one == 1
     dh, hq, hkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    cap = cache["k"].shape[1]
-
-    q = (x @ params["wq"]).reshape(b, 1, hq, dh)
-    cross = kv_source is not None
-    if cross:
-        # cross-attention: static kv from encoder output, no cache update
-        sk = kv_source.shape[1]
-        k = (kv_source @ params["wk"]).reshape(b, sk, hkv, dh)
-        v = (kv_source @ params["wv"]).reshape(b, sk, hkv, dh)
-        scale = cfg.scale if cfg.scale is not None else 1.0 / math.sqrt(dh)
-        out = dot_product_attention(q, k, v, None, scale)
-        return out.reshape(b, 1, hq * dh) @ params["wo"], cache
+    cap = cache["k"].shape[3]
 
     pos = jnp.full((b, 1), cache_len, jnp.int32)
+    q = (x @ params["wq"]).reshape(b, 1, hq, dh)
     q = apply_rope(q, pos, cfg.rope_theta, cfg.rope_style)
     k_new = (x @ params["wk"]).reshape(b, 1, hkv, dh)
     k_new = apply_rope(k_new, pos, cfg.rope_theta, cfg.rope_style)
     v_new = (x @ params["wv"]).reshape(b, 1, hkv, dh)
 
+    # The token is written in the cache's own (row-major) layout. Without
+    # the constraint XLA gives V's write the projection's layout and
+    # relayouts the whole stack on entry and exit of the layer scan.
     slot = jnp.mod(cache_len, cap)
+    row_layout = Layout(major_to_minor=(0, 1, 2, 3, 4))
     with jax.named_scope("kv_cache_update"):
-        k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, slot, axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, slot, axis=1)
+        def write(stack, new):                    # new (B, 1, Hkv, Dh)
+            row = with_layout_constraint(new.transpose(0, 2, 1, 3)[None],
+                                         row_layout)
+            return jax.lax.dynamic_update_slice(stack, row,
+                                                (layer, 0, 0, slot, 0))
+        cache = {"k": write(cache["k"], k_new), "v": write(cache["v"], v_new)}
+    k = jax.lax.dynamic_index_in_dim(cache["k"], layer, 0, keepdims=False)
+    v = jax.lax.dynamic_index_in_dim(cache["v"], layer, 0, keepdims=False)
 
     # Ring buffer: absolute position stored at slot i is the largest p <= L
     # with p % cap == i, i.e. abs(i) = L - ((L - i) mod cap); L = cache_len
@@ -281,10 +289,14 @@ def attention_decode_step(params: Params, x: jnp.ndarray, cache: Dict[str, jnp.n
     window = cfg.sliding_window if window_override is None else window_override
     if window > 0:
         valid &= (cache_len - abs_pos) < window
-    bias = jnp.where(valid, 0.0, NEG_INF)[None, None, :]  # (1, 1, cap)
+    bias = jnp.where(valid, 0.0, NEG_INF)                 # (cap,)
 
     scale = cfg.scale if cfg.scale is not None else 1.0 / math.sqrt(dh)
-    out = dot_product_attention(q, k_cache, v_cache,
-                                jnp.broadcast_to(bias, (b, 1, cap)), scale)
+    with jax.named_scope("attention_core"):
+        qg = q.reshape(b, hkv, hq // hkv, dh)
+        logits = jnp.einsum("bhgd,bhkd->bhgk", qg.astype(jnp.float32),
+                            k.astype(jnp.float32)) * scale + bias
+        probs = jax.nn.softmax(logits, axis=-1)
+        out = jnp.einsum("bhgk,bhkd->bhgd", probs.astype(v.dtype), v)
     out = out.reshape(b, 1, hq * dh) @ params["wo"]
-    return out, {"k": k_cache, "v": v_cache}
+    return out, cache

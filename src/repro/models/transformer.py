@@ -185,6 +185,17 @@ def stack_apply(params: Params, x: jnp.ndarray, cfg: ModelConfig,
 # decode caches (stacked to match scan)
 # ==========================================================================
 
+def _cache_len(cfg: ModelConfig, j: int, max_len: int) -> int:
+    """Slots of attention sub-layer ``j``'s cache: sliding-window layers
+    keep only ``window`` (a ring buffer)."""
+    acfg = cfg.attention
+    if acfg.local_global != (0, 0) and not cfg.layer_is_global_attn(j):
+        return min(max_len, acfg.sliding_window)
+    if acfg.sliding_window > 0 and acfg.local_global == (0, 0):
+        return min(max_len, acfg.sliding_window)
+    return max_len
+
+
 def stack_init_cache(cfg: ModelConfig, batch: int, max_len: int, *,
                      dtype=jnp.bfloat16, has_cross: bool = False) -> Pytree:
     """Per-layer decode caches, stacked (n_groups, ...) like the params.
@@ -195,16 +206,10 @@ def stack_init_cache(cfg: ModelConfig, batch: int, max_len: int, *,
     n_groups = cfg.n_layers // period
 
     def one_layer(j):
-        kind = cfg.layer_kind(j)
-        if kind == "ssm":
+        if cfg.layer_kind(j) == "ssm":
             return ssm_mod.init_ssm_cache(batch, cfg.d_model, cfg.ssm, dtype)
-        acfg = cfg.attention
-        length = max_len
-        if acfg.local_global != (0, 0) and not cfg.layer_is_global_attn(j):
-            length = min(max_len, acfg.sliding_window)
-        elif acfg.sliding_window > 0 and acfg.local_global == (0, 0):
-            length = min(max_len, acfg.sliding_window)
-        return attn.init_kv_cache(batch, length, acfg, dtype)
+        return attn.init_kv_cache(batch, _cache_len(cfg, j, max_len),
+                                  cfg.attention, dtype)
 
     group = {f"sub{j}": one_layer(j) for j in range(period)}
     return jax.tree.map(
@@ -219,8 +224,8 @@ def cache_axes(cfg: ModelConfig) -> Pytree:
         if cfg.layer_kind(j) == "ssm":
             return {"conv": ("layers", "batch", None, "ssm_conv"),
                     "state": ("layers", "batch", "ssm_heads_cache", None, None)}
-        return {"k": ("layers", "batch", "cache_seq", "kv_heads_cache", None),
-                "v": ("layers", "batch", "cache_seq", "kv_heads_cache", None)}
+        return {"k": ("layers", "batch", "kv_heads_cache", "cache_seq", None),
+                "v": ("layers", "batch", "kv_heads_cache", "cache_seq", None)}
 
     return {f"sub{j}": one_layer(j) for j in range(period)}
 
@@ -238,14 +243,6 @@ def stack_prefill(params: Params, x: jnp.ndarray, cfg: ModelConfig,
     """
     period = cfg.layer_period
 
-    def cache_len_for(j: int) -> int:
-        acfg = cfg.attention
-        if acfg.local_global != (0, 0) and not cfg.layer_is_global_attn(j):
-            return min(max_len, acfg.sliding_window)
-        if acfg.sliding_window > 0 and acfg.local_global == (0, 0):
-            return min(max_len, acfg.sliding_window)
-        return max_len
-
     def group_body(carry, gparams):
         h, aux = carry
         gcache = {}
@@ -259,7 +256,7 @@ def stack_prefill(params: Params, x: jnp.ndarray, cfg: ModelConfig,
                 if acfg.local_global != (0, 0):
                     window = 0 if cfg.layer_is_global_attn(j) else acfg.sliding_window
                 out, c = attn.attention_prefill(p["mixer"], hin, acfg, positions,
-                                                cache_len_for(j),
+                                                _cache_len(cfg, j, max_len),
                                                 window_override=window, impl=impl)
             else:
                 out, c = ssm_mod.ssm_prefill(p["mixer"], hin, cfg.d_model,
@@ -298,32 +295,48 @@ def stack_decode_step(params: Params, cache: Pytree, x: jnp.ndarray,
                       cache_len: jnp.ndarray, cfg: ModelConfig, *,
                       enc_out: Optional[jnp.ndarray] = None,
                       ) -> Tuple[jnp.ndarray, Pytree]:
-    """One-token decode through the whole stack. x: (B, 1, D)."""
-    period = cfg.layer_period
+    """One-token decode through the whole stack. x: (B, 1, D).
 
-    def group_body(h, scanned):
-        gparams, gcache = scanned
-        new_gcache = {}
+    The stacked cache rides in the layer scan's carry, so a donated cache
+    is updated in place: attention layers write their one token into it
+    and read their own row where it lies; SSM layers read their small
+    state out and write it back whole.
+    """
+    from repro.runtime import telemetry
+
+    period = cfg.layer_period
+    n_groups = cfg.n_layers // period
+    n_attn = sum(cfg.layer_kind(j) == "attn" for j in range(period))
+    telemetry.count("decode.cache_layers_in_place", n_groups * n_attn)
+    telemetry.count("decode.cache_layers_copied", n_groups * (period - n_attn))
+
+    def group_body(carry, scanned):
+        h, cache = carry
+        gparams, layer = scanned
+        cache = dict(cache)
         for j in range(period):
-            p, c = gparams[f"sub{j}"], gcache[f"sub{j}"]
-            kind = cfg.layer_kind(j)
+            p, c = gparams[f"sub{j}"], cache[f"sub{j}"]
             hin = rmsnorm(p["norm1"], h, cfg.norm_eps)
-            if kind == "attn":
+            if cfg.layer_kind(j) == "attn":
                 acfg = cfg.attention
                 window = None
                 if acfg.local_global != (0, 0):
                     window = 0 if cfg.layer_is_global_attn(j) else acfg.sliding_window
-                out, c = attn.attention_decode_step(p["mixer"], hin, c, cache_len,
-                                                    acfg, window_override=window)
+                out, c = attn.attention_decode_step(p["mixer"], hin, c, layer,
+                                                    cache_len, acfg,
+                                                    window_override=window)
             else:
-                out, c = ssm_mod.ssm_decode_step(p["mixer"], hin, c,
-                                                 cfg.d_model, cfg.ssm)
+                row = jax.tree.map(lambda t: jax.lax.dynamic_index_in_dim(
+                    t, layer, 0, keepdims=False), c)
+                out, row = ssm_mod.ssm_decode_step(p["mixer"], hin, row,
+                                                   cfg.d_model, cfg.ssm)
+                c = jax.tree.map(lambda t, r: jax.lax.dynamic_update_index_in_dim(
+                    t, r, layer, 0), c, row)
             h = h + out
             if "cross" in p:
                 hin = rmsnorm(p["norm_cross"], h, cfg.norm_eps)
-                out, _ = attn.attention_decode_step(p["cross"], hin, c, cache_len,
-                                                    cfg.attention, kv_source=enc_out)
-                h = h + out
+                h = h + attn.attention_apply(p["cross"], hin, cfg.attention,
+                                             None, kv_source=enc_out)
             if "ffn" in p:
                 hin = rmsnorm(p["norm2"], h, cfg.norm_eps)
                 if cfg.layer_is_moe(j):
@@ -331,8 +344,9 @@ def stack_decode_step(params: Params, cache: Pytree, x: jnp.ndarray,
                 else:
                     out = mlp_apply(p["ffn"], hin, cfg.act)
                 h = h + out
-            new_gcache[f"sub{j}"] = c
-        return h, new_gcache
+            cache[f"sub{j}"] = c
+        return (h, cache), None
 
-    x, new_cache = jax.lax.scan(group_body, x, (params, cache))
-    return x, new_cache
+    (x, cache), _ = jax.lax.scan(group_body, (x, cache),
+                                 (params, jnp.arange(n_groups)))
+    return x, cache

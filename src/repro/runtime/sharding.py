@@ -62,7 +62,7 @@ def axis_rules(cfg: ModelConfig, mesh: Mesh, mesh_cfg: MeshConfig,
     # not the weights. See EXPERIMENTS §Perf cell C iteration C2.)
     a = cfg.attention
     model_size = mesh.shape["model"] if "model" in mesh.axis_names else 1
-    # KV-cache fallback: the cache layout is (..., seq, n_kv_heads, head_dim)
+    # KV-cache fallback: the cache layout is (..., n_kv_heads, seq, head_dim)
     # with the *head count* as its own dim — when it doesn't divide the
     # model axis (GQA kv=8 or 2 on a 16-way axis), shard the cache's
     # sequence dim instead (paged-KV style; XLA inserts the ring-update
@@ -263,21 +263,10 @@ def cache_shardings(cfg: ModelConfig, mesh: Mesh, mesh_cfg: MeshConfig,
         # shard KV pages over "data" (plus "model" too when the kv-head dim
         # can't use it) — sequence parallelism for the cache
         if rules.get("kv_heads_cache") is None:
-            rules["cache_seq2"] = ("data", "model")
+            rules["cache_seq"] = ("data", "model")
         else:
-            rules["cache_seq2"] = ("data",)
+            rules["cache_seq"] = ("data",)
     ax = cache_axes(cfg)
-    if batch % prod != 0:
-        # rewrite attention cache axes: seq dim gets "cache_seq2"
-        def rewrite(t):
-            if isinstance(t, tuple) and len(t) >= 3 and t[1] == "batch":
-                lst = list(t)
-                if lst[2] in (None, "cache_seq"):
-                    lst[2] = "cache_seq2"
-                return tuple(lst)
-            return t
-        ax = jax.tree.map(rewrite, ax,
-                          is_leaf=lambda t: isinstance(t, tuple))
     # decode state = {"cache": ..., "length": scalar}
     state_axes = {"cache": ax, "length": ()}
     return shardings_for(cache_abstract, state_axes, mesh, rules, report)

@@ -94,6 +94,149 @@ def test_prefill_matches_stepwise_decode(arch):
     assert max(jax.tree.leaves(cache_err)) < 5e-4
 
 
+# --------------------------------------------------------------------------
+# plain oracle of the decode step: the stacked cache as the layer scan's
+# xs/ys, each layer's K/V sequence-major (B, S, Hkv, Dh)
+# --------------------------------------------------------------------------
+
+def _oracle_attention_decode_step(params, x, cache, cache_len, cfg, *,
+                                  window_override=None, kv_source=None):
+    from repro.models.layers import apply_rope
+    b = x.shape[0]
+    dh, hq, hkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
+    cap = cache["k"].shape[1]
+    scale = cfg.scale if cfg.scale is not None else 1.0 / np.sqrt(dh)
+    q = (x @ params["wq"]).reshape(b, 1, hq, dh)
+    if kv_source is not None:
+        sk = kv_source.shape[1]
+        k = (kv_source @ params["wk"]).reshape(b, sk, hkv, dh)
+        v = (kv_source @ params["wv"]).reshape(b, sk, hkv, dh)
+        out = dot_product_attention(q, k, v, None, scale)
+        return out.reshape(b, 1, hq * dh) @ params["wo"], cache
+    pos = jnp.full((b, 1), cache_len, jnp.int32)
+    q = apply_rope(q, pos, cfg.rope_theta, cfg.rope_style)
+    k_new = (x @ params["wk"]).reshape(b, 1, hkv, dh)
+    k_new = apply_rope(k_new, pos, cfg.rope_theta, cfg.rope_style)
+    v_new = (x @ params["wv"]).reshape(b, 1, hkv, dh)
+    slot = jnp.mod(cache_len, cap)
+    k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, slot, axis=1)
+    v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, slot, axis=1)
+    idx = jnp.arange(cap)
+    abs_pos = cache_len - jnp.mod(cache_len - idx, cap)
+    valid = abs_pos >= 0
+    window = cfg.sliding_window if window_override is None else window_override
+    if window > 0:
+        valid &= (cache_len - abs_pos) < window
+    bias = jnp.where(valid, 0.0, -1e30)[None, None, :]
+    out = dot_product_attention(q, k_cache, v_cache,
+                                jnp.broadcast_to(bias, (b, 1, cap)), scale)
+    out = out.reshape(b, 1, hq * dh) @ params["wo"]
+    return out, {"k": k_cache, "v": v_cache}
+
+
+def _oracle_stack_decode_step(params, cache, x, cache_len, cfg, enc_out):
+    from repro.models import moe as moe_mod, ssm as ssm_mod
+    from repro.models.layers import mlp_apply, rmsnorm
+
+    def group_body(h, scanned):
+        gparams, gcache = scanned
+        new_gcache = {}
+        for j in range(cfg.layer_period):
+            p, c = gparams[f"sub{j}"], gcache[f"sub{j}"]
+            hin = rmsnorm(p["norm1"], h, cfg.norm_eps)
+            if cfg.layer_kind(j) == "attn":
+                acfg = cfg.attention
+                window = None
+                if acfg.local_global != (0, 0):
+                    window = 0 if cfg.layer_is_global_attn(j) else acfg.sliding_window
+                out, c = _oracle_attention_decode_step(
+                    p["mixer"], hin, c, cache_len, acfg, window_override=window)
+            else:
+                out, c = ssm_mod.ssm_decode_step(p["mixer"], hin, c,
+                                                 cfg.d_model, cfg.ssm)
+            h = h + out
+            if "cross" in p:
+                hin = rmsnorm(p["norm_cross"], h, cfg.norm_eps)
+                out, _ = _oracle_attention_decode_step(
+                    p["cross"], hin, c, cache_len, cfg.attention,
+                    kv_source=enc_out)
+                h = h + out
+            if "ffn" in p:
+                hin = rmsnorm(p["norm2"], h, cfg.norm_eps)
+                if cfg.layer_is_moe(j):
+                    out, _ = moe_mod.moe_apply(p["ffn"], hin, cfg.moe, cfg.act)
+                else:
+                    out = mlp_apply(p["ffn"], hin, cfg.act)
+                h = h + out
+            new_gcache[f"sub{j}"] = c
+        return h, new_gcache
+
+    return jax.lax.scan(group_body, x, (params, cache))
+
+
+def _oracle_decode_step(params, state, token, cfg, enc_out):
+    from repro.models.layers import embed, rmsnorm, unembed
+    from repro.models.model import _sin_row, mask_pad_logits
+    x = embed(params["embed"], token[:, None])
+    if cfg.attention is not None and cfg.attention.rope_style == "none" \
+            and cfg.encoder_layers > 0:
+        x = x + _sin_row(state["length"], cfg.d_model).astype(x.dtype)[None, None]
+    x, cache = _oracle_stack_decode_step(params["stack"], state["cache"], x,
+                                         state["length"], cfg, enc_out)
+    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    head = params["unembed"] if "unembed" in params else params["embed"]
+    logits = mask_pad_logits(unembed(head, x)[:, 0, :], cfg)
+    return logits, {"cache": cache, "length": state["length"] + 1}
+
+
+def _sequence_major(cache):
+    """The head-major attention cache (L, B, Hkv, S, Dh) in the oracle's
+    (L, B, S, Hkv, Dh); SSM leaves keep their layout."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, t: t.transpose(0, 1, 3, 2, 4)
+        if path[-1].key in ("k", "v") else t, cache)
+
+
+@pytest.mark.parametrize("arch,max_len,steps", [
+    ("gemma3-12b", 32, 34),          # 6 + 34 = 40 positions: both rings wrap
+    ("granite-3-8b", 16, 8),
+    ("mamba2-2.7b", 16, 8),
+    ("jamba-1.5-large-398b", 16, 8),
+    ("whisper-medium", 16, 8)])
+def test_decode_step_matches_sequence_major_oracle(arch, max_len, steps):
+    """The in-place decode step gives the oracle's logits and cache, through
+    a prefill of 6 tokens and ``steps`` decode steps past it."""
+    cfg = dataclasses.replace(get_reduced(arch), dtype="float32")
+    if cfg.moe is not None:
+        cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+            cfg.moe, capacity_factor=float(cfg.moe.n_experts)))  # no drops
+    params = init_params(jax.random.PRNGKey(3), cfg)
+    toks = jax.random.randint(jax.random.PRNGKey(4), (B, 6 + steps), 1,
+                              cfg.vocab_size)
+    kw, enc_out = {}, None
+    if cfg.encoder_layers > 0:
+        kw["enc_feats"] = jnp.ones(stub_feature_shape(cfg, B, 16),
+                                   jnp.float32) * 0.1
+        from repro.models.model import encode
+        enc_out = encode(params, kw["enc_feats"], cfg)
+    _, state = prefill(params, toks[:, :6], cfg, max_len, **kw)
+    ref = {"cache": _sequence_major(state["cache"]), "length": state["length"]}
+    step = jax.jit(lambda s, t: decode_step(params, s, t, cfg, enc_out=enc_out))
+    ref_step = jax.jit(lambda s, t: _oracle_decode_step(params, s, t, cfg,
+                                                        enc_out))
+    for t in range(6, 6 + steps):
+        logits, state = step(state, toks[:, t])
+        ref_logits, ref = ref_step(ref, toks[:, t])
+        np.testing.assert_allclose(np.asarray(logits), np.asarray(ref_logits),
+                                   rtol=1e-5, atol=1e-5)
+    got = _sequence_major(state["cache"])
+    assert jax.tree.structure(got) == jax.tree.structure(ref["cache"])
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref["cache"])):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+
 def test_chunked_attention_equals_dense():
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (2, 80, 4, 16))

@@ -59,6 +59,29 @@ def test_axis_rules_kv_fallback():
     assert rules_w["kv_heads_cache"] == ("model",)
 
 
+@pytest.mark.parametrize("arch,mesh_shape,seq_axes,head_axes", [
+    ("granite-3-8b", (2, 16), ("data", "model"), None),   # kv 8 < model 16
+    ("whisper-medium", (2, 4), "data", "model")])         # kv 16 on model 4
+def test_cache_shardings_shard_sequence_by_name(arch, mesh_shape, seq_axes,
+                                                head_axes):
+    """A batch the data axis does not divide: each attention cache shards
+    its sequence dimension, found by its name in ``cache_axes``, in the
+    head-major layout (layers, batch, kv heads, seq, head_dim)."""
+    import dataclasses
+    from jax.sharding import AbstractMesh
+    from repro.models.model import init_decode_state
+    from repro.runtime.sharding import cache_shardings
+    bundle = get_bundle(arch)
+    cfg = dataclasses.replace(bundle.model, n_layers=2)
+    mesh = AbstractMesh(mesh_shape, ("data", "model"))
+    state = jax.eval_shape(lambda: init_decode_state(cfg, 1, 4096))
+    sh = cache_shardings(cfg, mesh, bundle.mesh, state, batch=1)
+    for kv in ("k", "v"):
+        assert state["cache"]["sub0"][kv].shape[3] == 4096
+        assert sh["cache"]["sub0"][kv].spec == P(None, None, head_axes,
+                                                 seq_axes, None)
+
+
 def test_shardings_for_on_host_mesh():
     """End-to-end sharding build on the 1-device host mesh — the same code
     path the 256/512-chip dry-run uses."""

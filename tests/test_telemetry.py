@@ -80,6 +80,29 @@ def test_counters():
     assert telemetry.summary() == {"spans": {}, "counters": {}}
 
 
+@pytest.mark.parametrize("arch,in_place,copied", [
+    ("granite-3-8b", 2, 0),            # every layer attention
+    ("mamba2-2.7b", 0, 2),             # every layer SSM
+    ("jamba-1.5-large-398b", 1, 7)])   # 1 attention + 7 mamba a group
+def test_decode_step_counts_cache_layers_by_kind(arch, in_place, copied):
+    """Tracing the decode step counts the layers whose cache takes the
+    one-token in-place write and those whose state is copied back whole;
+    running the traced step counts nothing more."""
+    from repro.models.model import init_decode_state, init_params
+    from repro.runtime.serve_loop import make_serve_step
+    cfg = get_reduced(arch)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    state = init_decode_state(cfg, 2, 8)
+    step = jax.jit(make_serve_step(cfg))
+    telemetry.reset()
+    step(params, state, jnp.ones((2,), jnp.int32))
+    c = telemetry.summary()["counters"]
+    assert c.get("decode.cache_layers_in_place", 0) == in_place
+    assert c.get("decode.cache_layers_copied", 0) == copied
+    step(params, state, jnp.ones((2,), jnp.int32))
+    assert telemetry.summary()["counters"] == c
+
+
 def test_a_fresh_jit_compiles_once():
     f = jax.jit(lambda x: jnp.sin(x) * 3.0 + 1.0)
     x, y = jnp.arange(8.0), jnp.ones(8)

@@ -6,6 +6,8 @@ what catches a block layout that Mosaic refuses or a step that does not fit
 the chip's 16 GiB, which the CPU interpret-mode tests never see.
 """
 import dataclasses
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -101,3 +103,71 @@ def test_granite_decode_step_fits_one_chip(one_chip):
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert m.alias_size_in_bytes > 0          # the cache updates in place
     assert peak < V5E_HBM_BYTES, peak
+
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\((.*)")
+_NOT_MATERIALIZED = {"parameter", "get-tuple-element", "bitcast"}
+
+
+def _materialized(hlo: str, min_elems: int):
+    """(name, opcode, update elements) of every value of ``min_elems`` or
+    more that the program writes to memory: the instructions outside fused
+    computations, each fusion named by its root's opcode; ``update`` is a
+    dynamic-update-slice's update size, else None."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line == "}":
+            cur = None
+        elif cur is not None and (m := _INSTR.match(line)):
+            cur.append(m.groups())
+    fused = set(re.findall(r"calls=%([\w.\-]+)", hlo))
+    elems = {name: math.prod(int(d) for d in dims.split(",") if d)
+             for instrs in comps.values() for name, dims, _, _ in instrs}
+
+    def update_of(operands):
+        return elems.get(re.findall(r"%([\w.\-]+)", operands)[1])
+
+    out = []
+    for comp, instrs in comps.items():
+        if comp in fused:
+            continue
+        for name, _, op, rest in instrs:
+            if elems[name] < min_elems or op in _NOT_MATERIALIZED:
+                continue
+            if op == "fusion":
+                callee = re.search(r"calls=%([\w.\-]+)", rest).group(1)
+                _, _, op, rest = comps[callee][-1]      # the fusion's root
+            update = update_of(rest) if op == "dynamic-update-slice" else None
+            out.append((name, op, update))
+    return out
+
+
+def test_granite_decode_step_writes_one_token_in_place(one_chip):
+    """The serving cell's decode step (granite widths, batch 32, 2304 slots,
+    cache donated): the cache rides in the layer scan's carry, each layer
+    writes its one token into it in place, and nothing of one layer's K or
+    V or more is copied, sliced out or held as scratch."""
+    cfg = dataclasses.replace(get_config("granite-3-8b"), n_layers=2)
+    batch, slots = 32, 2304
+    params = jax.eval_shape(lambda k: init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    state = jax.eval_shape(lambda: init_decode_state(cfg, batch, slots))
+    tok = jax.ShapeDtypeStruct((batch,), jnp.int32)
+    compiled = jax.jit(make_serve_step(cfg), donate_argnums=(1,)).lower(
+        *_on(one_chip, (params, state, tok))).compile()
+    acfg = cfg.attention
+    layer_elems = batch * slots * acfg.n_kv_heads * acfg.head_dim
+    layer_bytes = 2 * layer_elems                             # bf16
+    big = _materialized(compiled.as_text(), layer_elems)
+    assert big, "the cache writes were not found"
+    for name, op, update in big:
+        assert op == "dynamic-update-slice" and update < layer_elems, \
+            (name, op, update)
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < layer_bytes, m.temp_size_in_bytes
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(state["cache"]))
+    assert m.alias_size_in_bytes >= cache_bytes
