@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, padded_vocab_size
 from repro.models import frontends, transformer
+from repro.models.moe import MoEStats
 from repro.models.layers import (
     embed, embedding_init, rmsnorm, rmsnorm_init, sinusoidal_positions, unembed,
 )
@@ -88,8 +89,8 @@ def encode(params: Pytree, enc_feats: jnp.ndarray, cfg: ModelConfig, *,
     s = x.shape[1]
     x = x + sinusoidal_positions(s, cfg.d_model)[None].astype(x.dtype)
     pos = jnp.broadcast_to(jnp.arange(s)[None], x.shape[:2])
-    x, _ = transformer.stack_apply(params["encoder"], x, enc_cfg, pos,
-                                   causal=False, impl=impl, remat=remat)
+    x, _, _ = transformer.stack_apply(params["encoder"], x, enc_cfg, pos,
+                                      causal=False, impl=impl, remat=remat)
     return rmsnorm(params["enc_norm"], x, cfg.norm_eps)
 
 
@@ -98,8 +99,9 @@ def hidden_states(params: Pytree, tokens: Optional[jnp.ndarray],
                   input_embeds: Optional[jnp.ndarray] = None,
                   enc_feats: Optional[jnp.ndarray] = None,
                   impl: str = "xla", remat: str = "none", constrain=None,
-                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Final-norm hidden states (B,S,D) + moe aux loss (pre-unembed)."""
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray, MoEStats]:
+    """Final-norm hidden states (B,S,D) (pre-unembed), the moe aux loss and
+    the moe dispatch's row counts."""
     if input_embeds is not None:
         x = frontends.adapter_apply(params["adapter"], input_embeds)
     else:
@@ -119,13 +121,13 @@ def hidden_states(params: Pytree, tokens: Optional[jnp.ndarray],
 
     if constrain is not None:
         x = constrain(x)
-    x, aux = transformer.stack_apply(params["stack"], x, cfg, pos,
-                                     enc_out=enc_out, impl=impl, remat=remat,
-                                     constrain=constrain)
+    x, aux, stats = transformer.stack_apply(params["stack"], x, cfg, pos,
+                                            enc_out=enc_out, impl=impl, remat=remat,
+                                            constrain=constrain)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     if constrain is not None:
         x = constrain(x, kind="hidden")
-    return x, aux
+    return x, aux, stats
 
 
 def forward(params: Pytree, tokens: Optional[jnp.ndarray], cfg: ModelConfig, *,
@@ -134,9 +136,9 @@ def forward(params: Pytree, tokens: Optional[jnp.ndarray], cfg: ModelConfig, *,
             impl: str = "xla", remat: str = "none", constrain=None,
             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (logits (B,S,V), moe_aux_loss)."""
-    x, aux = hidden_states(params, tokens, cfg, input_embeds=input_embeds,
-                           enc_feats=enc_feats, impl=impl, remat=remat,
-                           constrain=constrain)
+    x, aux, _ = hidden_states(params, tokens, cfg, input_embeds=input_embeds,
+                              enc_feats=enc_feats, impl=impl, remat=remat,
+                              constrain=constrain)
     head = params["unembed"] if "unembed" in params else params["embed"]
     logits = unembed(head, x)
     if constrain is not None:
@@ -199,31 +201,41 @@ def loss_fn(params: Pytree, batch: Dict[str, jnp.ndarray], cfg: ModelConfig, *,
             ) -> jnp.ndarray:
     """Next-token cross-entropy (+ MoE aux). batch keys: tokens|input_embeds,
     labels, and enc_feats for enc-dec archs."""
+    return loss_and_stats(params, batch, cfg, impl=impl, remat=remat,
+                          constrain=constrain)[0]
+
+
+def loss_and_stats(params: Pytree, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
+                   *, impl: str = "xla", remat: str = "none", constrain=None,
+                   ) -> Tuple[jnp.ndarray, MoEStats]:
+    """``loss_fn``'s loss, and the row counts of its MoE dispatches."""
     labels = batch["labels"]
+    x, aux, stats = hidden_states(params, batch.get("tokens"), cfg,
+                                  input_embeds=batch.get("input_embeds"),
+                                  enc_feats=batch.get("enc_feats"),
+                                  impl=impl, remat=remat, constrain=constrain)
+    head = params["unembed"] if "unembed" in params else params["embed"]
     if padded_vocab_size(cfg) >= CHUNKED_XENT_VOCAB \
             and not os.environ.get("REPRO_NAIVE_LOSS") \
             and not os.environ.get("REPRO_DENSE_XENT"):
-        x, aux = hidden_states(params, batch.get("tokens"), cfg,
-                               input_embeds=batch.get("input_embeds"),
-                               enc_feats=batch.get("enc_feats"),
-                               impl=impl, remat=remat, constrain=constrain)
-        head = params["unembed"] if "unembed" in params else params["embed"]
         nll = chunked_softmax_xent(x, head["table"], labels, cfg.vocab_size)
-        mask = batch.get("loss_mask", jnp.ones_like(nll))
-        return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0) + aux
-    logits, aux = forward(params, batch.get("tokens"), cfg,
-                          input_embeds=batch.get("input_embeds"),
-                          enc_feats=batch.get("enc_feats"),
-                          impl=impl, remat=remat, constrain=constrain)
-    logits = mask_pad_logits(logits, cfg)
+    else:
+        logits = unembed(head, x)
+        if constrain is not None:
+            logits = constrain(logits, kind="logits")
+        nll = _dense_xent(mask_pad_logits(logits, cfg), labels)
+    mask = batch.get("loss_mask", jnp.ones_like(nll))
+    return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0) + aux, stats
+
+
+def _dense_xent(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
+    """Per-token nll (B,S) fp32 from whole (B,S,V) logits."""
     if os.environ.get("REPRO_NAIVE_LOSS"):
         # the pre-iteration-1 formulation kept for §Perf A/B measurement:
         # take_along_axis over the vocab axis forces GSPMD to materialize
         # gathered fp32 logits
         logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-        mask = batch.get("loss_mask", jnp.ones_like(nll))
-        return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0) + aux
+        return -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
     # Cross-entropy in logsumexp + select-reduce form: every op is
     # elementwise or a reduction along vocab, so GSPMD keeps the logits
     # vocab-sharded end-to-end (partial reductions + a scalar-ish
@@ -235,9 +247,7 @@ def loss_fn(params: Pytree, batch: Dict[str, jnp.ndarray], cfg: ModelConfig, *,
     vocab_iota = jnp.arange(logits.shape[-1], dtype=labels.dtype)
     true_logit = jnp.sum(
         jnp.where(labels[..., None] == vocab_iota, logits_f, 0.0), axis=-1)
-    nll = lse - true_logit
-    mask = batch.get("loss_mask", jnp.ones_like(nll))
-    return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0) + aux
+    return lse - true_logit
 
 
 # --------------------------------------------------------------------------

@@ -89,10 +89,11 @@ def _layer_axes(cfg: ModelConfig, idx: int, *, cross: bool = False) -> Pytree:
 def _layer_apply(p: Params, x: jnp.ndarray, cfg: ModelConfig, idx: int,
                  positions: jnp.ndarray, *, enc_out: Optional[jnp.ndarray] = None,
                  causal: bool = True, impl: str = "xla", constrain=None,
-                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Pre-norm residual layer. Returns (x, moe_aux_loss)."""
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray, moe_mod.MoEStats]:
+    """Pre-norm residual layer. Returns (x, moe_aux_loss, moe stats)."""
     kind = cfg.layer_kind(idx)
     aux = jnp.zeros((), jnp.float32)
+    stats = moe_mod.MoEStats.zeros()
     h = rmsnorm(p["norm1"], x, cfg.norm_eps)
     if kind == "attn":
         acfg = cfg.attention
@@ -115,12 +116,12 @@ def _layer_apply(p: Params, x: jnp.ndarray, cfg: ModelConfig, idx: int,
     if "ffn" in p:
         h = rmsnorm(p["norm2"], x, cfg.norm_eps)
         if cfg.layer_is_moe(idx):
-            h, aux = moe_mod.moe_apply(p["ffn"], h, cfg.moe, cfg.act,
-                                       constrain=constrain)
+            h, aux, stats = moe_mod.moe_layer(p["ffn"], h, cfg.moe, cfg.act,
+                                              constrain=constrain)
         else:
             h = mlp_apply(p["ffn"], h, cfg.act)
         x = x + h
-    return x, aux
+    return x, aux, stats
 
 
 # ==========================================================================
@@ -153,21 +154,26 @@ def stack_axes(cfg: ModelConfig, *, cross: bool = False) -> Pytree:
 def stack_apply(params: Params, x: jnp.ndarray, cfg: ModelConfig,
                 positions: jnp.ndarray, *, enc_out: Optional[jnp.ndarray] = None,
                 causal: bool = True, impl: str = "xla", remat: str = "none",
-                constrain=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """constrain: optional h -> h sharding hook applied to the residual
+                constrain=None,
+                ) -> Tuple[jnp.ndarray, jnp.ndarray, moe_mod.MoEStats]:
+    """Returns (x, moe aux loss, moe stats summed over layers).
+
+    constrain: optional h -> h sharding hook applied to the residual
     stream at group boundaries (sequence-parallel saved activations)."""
     period = cfg.layer_period
+    _count_moe_layers(cfg, x)
 
     def group_body(carry, gparams):
-        h, aux = carry
+        h, aux, stats = carry
         for j in range(period):
-            h, aux_j = _layer_apply(gparams[f"sub{j}"], h, cfg, j, positions,
-                                    enc_out=enc_out, causal=causal, impl=impl,
-                                    constrain=constrain)
-            aux = aux + aux_j
+            h, aux_j, stats_j = _layer_apply(gparams[f"sub{j}"], h, cfg, j,
+                                             positions, enc_out=enc_out,
+                                             causal=causal, impl=impl,
+                                             constrain=constrain)
+            aux, stats = aux + aux_j, stats.plus(stats_j)
         if constrain is not None:
             h = constrain(h)
-        return (h, aux), None
+        return (h, aux, stats), None
 
     if remat == "full":
         group_body = jax.checkpoint(group_body,
@@ -176,9 +182,22 @@ def stack_apply(params: Params, x: jnp.ndarray, cfg: ModelConfig,
         group_body = jax.checkpoint(
             group_body, policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
 
-    (x, aux), _ = jax.lax.scan(group_body,
-                               (x, jnp.zeros((), jnp.float32)), params)
-    return x, aux
+    (x, aux, stats), _ = jax.lax.scan(
+        group_body, (x, jnp.zeros((), jnp.float32), moe_mod.MoEStats.zeros()),
+        params)
+    return x, aux, stats
+
+
+def _count_moe_layers(cfg: ModelConfig, x: jnp.ndarray) -> None:
+    """Counts, when a stack is traced, its MoE layers by the dispatch path
+    that a call on ``x`` (B, S, D) takes."""
+    from repro.runtime import telemetry
+
+    n = (cfg.n_layers // cfg.layer_period) * sum(
+        cfg.layer_is_moe(j) for j in range(cfg.layer_period))
+    if n:
+        grouped = moe_mod.takes_grouped_path(cfg.moe, x.shape[0], x.shape[1])
+        telemetry.count(f"moe.layers_{'grouped' if grouped else 'capacity'}", n)
 
 
 # ==========================================================================
@@ -242,6 +261,7 @@ def stack_prefill(params: Params, x: jnp.ndarray, cfg: ModelConfig,
     stack_decode_step continues seamlessly with cache_len = S.
     """
     period = cfg.layer_period
+    _count_moe_layers(cfg, x)
 
     def group_body(carry, gparams):
         h, aux = carry
@@ -271,7 +291,7 @@ def stack_prefill(params: Params, x: jnp.ndarray, cfg: ModelConfig,
             if "ffn" in p:
                 hin = rmsnorm(p["norm2"], h, cfg.norm_eps)
                 if cfg.layer_is_moe(j):
-                    out, aux_j = moe_mod.moe_apply(p["ffn"], hin, cfg.moe, cfg.act)
+                    out, aux_j, _ = moe_mod.moe_layer(p["ffn"], hin, cfg.moe, cfg.act)
                     aux = aux + aux_j
                 else:
                     out = mlp_apply(p["ffn"], hin, cfg.act)
@@ -309,6 +329,7 @@ def stack_decode_step(params: Params, cache: Pytree, x: jnp.ndarray,
     n_attn = sum(cfg.layer_kind(j) == "attn" for j in range(period))
     telemetry.count("decode.cache_layers_in_place", n_groups * n_attn)
     telemetry.count("decode.cache_layers_copied", n_groups * (period - n_attn))
+    _count_moe_layers(cfg, x)
 
     def group_body(carry, scanned):
         h, cache = carry
@@ -340,7 +361,7 @@ def stack_decode_step(params: Params, cache: Pytree, x: jnp.ndarray,
             if "ffn" in p:
                 hin = rmsnorm(p["norm2"], h, cfg.norm_eps)
                 if cfg.layer_is_moe(j):
-                    out, _ = moe_mod.moe_apply(p["ffn"], hin, cfg.moe, cfg.act)
+                    out, _, _ = moe_mod.moe_layer(p["ffn"], hin, cfg.moe, cfg.act)
                 else:
                     out = mlp_apply(p["ffn"], hin, cfg.act)
                 h = h + out

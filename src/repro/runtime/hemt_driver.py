@@ -32,6 +32,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchBundle, ModelConfig
@@ -182,6 +183,19 @@ class HeMTTrainer:
                         sum(int(v.nbytes) for v in block.values()))
         return out
 
+    def _wait(self, metrics: Dict) -> float:
+        """The step's loss, read back: the host waits here for the step.
+        The MoE dispatch's row counts come back in the same read and are
+        kept as per-step counters (``moe.routed_rows``, ``moe.dropped_rows``,
+        ``moe.max_expert_rows``)."""
+        with span("repro.train.wait"):
+            if self.cfg.moe is None:
+                return float(metrics["loss"])
+            loss, moe = jax.device_get((metrics["loss"], metrics["moe"]))
+        for name, rows in moe._asdict().items():
+            telemetry.record(f"moe.{name}", int(rows))
+        return float(loss)
+
     def run_step(self, state: TrainState) -> Tuple[TrainState, StepReport]:
         step = int(state.step)
         with span("repro.train.step", step=step):
@@ -197,8 +211,7 @@ class HeMTTrainer:
                      for name in counts if counts[name] > 0})
 
             self._clock += makespan
-            with span("repro.train.wait"):
-                loss = float(metrics["loss"])
+            loss = self._wait(metrics)
             rep = StepReport(step, self.mode, counts, elapsed, makespan, idle,
                              loss, steals)
             self.reports.append(rep)
@@ -294,8 +307,7 @@ class HeMTTrainer:
             step = int(state.step)
             with span("repro.train.step", step=step):
                 state, metrics = self._execute_math(state, counts, step)
-                with span("repro.train.wait"):
-                    loss = float(metrics["loss"])
+                loss = self._wait(metrics)
             telemetry.count("train.steps")
             rep = StepReport(step, self.mode, counts, elapsed, summ.span,
                              summ.idle_time, loss, 0)

@@ -7,7 +7,9 @@ parent is the innermost span open on the same thread; a span given a
 span also opens a ``jax.profiler.TraceAnnotation`` of the same name (a
 ``StepTraceAnnotation`` for a step), so in a captured profile the spans
 sit on the host plane beside the device's operations. Names read
-``repro.<layer>.<what>``.
+``repro.<layer>.<what>``. ``record`` adds to a counter and also keeps a
+zero-length record of the amount, under the open span and its step, so
+that a reader can take the count of each step.
 
 JAX's compile events become records too, ``repro.compile.trace``,
 ``.lower``, ``.backend`` (a compile or a persistent-cache load) and
@@ -41,6 +43,7 @@ class Record(NamedTuple):
     step: Optional[int]
     t0_ns: int
     t1_ns: int
+    value: Optional[int] = None      # a counted amount; None for a span
 
 
 _records: "collections.deque[Record]" = collections.deque(maxlen=MAXLEN)
@@ -84,6 +87,16 @@ def count(name: str, n: int = 1) -> None:
         _counters[name] += n
 
 
+def record(name: str, n: int) -> None:
+    """Adds ``n`` to the counter ``name`` and keeps a zero-length record of
+    ``n`` at this moment, under the open span and its step."""
+    count(name, n)
+    stack = _open()
+    parent, step = stack[-1] if stack else (None, None)
+    t = _now_ns()
+    _records.append(Record(name, parent, step, t, t, n))
+
+
 def records() -> List[Record]:
     """The ring buffer's records, oldest first, each appended as it closed."""
     return list(_records)
@@ -105,10 +118,12 @@ def covered_ns(intervals: Iterable[Tuple[int, int]]) -> int:
 def summary() -> dict:
     """Per span name its count, total and self milliseconds (self: the
     span's time less the part of it that its child spans cover), and the
-    counters."""
+    counters (the records of counted amounts are not spans)."""
     spans: Dict[str, dict] = {}
     children: Dict[str, List[Tuple[int, int]]] = collections.defaultdict(list)
     for r in records():
+        if r.value is not None:
+            continue
         s = spans.setdefault(r.name, {"count": 0, "total_ms": 0.0})
         s["count"] += 1
         s["total_ms"] += (r.t1_ns - r.t0_ns) / 1e6

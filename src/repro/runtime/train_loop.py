@@ -23,13 +23,14 @@ Two granularities:
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, NamedTuple, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchBundle, ModelConfig
-from repro.models.model import init_params, loss_fn
+from repro.models.model import init_params, loss_and_stats
+from repro.models.moe import MoEStats
 from repro.optim.adamw import AdamWState, adamw_init, adamw_update
 from repro.optim.compression import (
     CompressionState, compress_decompress, compression_init,
@@ -56,11 +57,6 @@ def train_state_init(key, cfg: ModelConfig, bundle: ArchBundle) -> TrainState:
     return TrainState(params, opt, jnp.zeros((), jnp.int32), ef)
 
 
-def _loss_with_aux(params, batch, cfg, impl, remat, constrain=None):
-    return loss_fn(params, batch, cfg, impl=impl, remat=remat,
-                   constrain=constrain)
-
-
 def make_train_step(cfg: ModelConfig, bundle: ArchBundle, *, impl: str = "xla",
                     constrain=None,
                     ) -> Callable[[TrainState, Dict[str, jnp.ndarray]],
@@ -72,8 +68,8 @@ def make_train_step(cfg: ModelConfig, bundle: ArchBundle, *, impl: str = "xla",
 
     def train_step(state: TrainState, batch: Dict[str, jnp.ndarray],
                    ) -> Tuple[TrainState, Dict[str, jnp.ndarray]]:
-        loss, grads = jax.value_and_grad(_loss_with_aux)(
-            state.params, batch, cfg, impl, remat, constrain)
+        (loss, moe), grads = jax.value_and_grad(loss_and_stats, has_aux=True)(
+            state.params, batch, cfg, impl=impl, remat=remat, constrain=constrain)
         ef = state.ef
         if tc.compression != "none":
             sent, new_cs = compress_decompress(
@@ -87,7 +83,7 @@ def make_train_step(cfg: ModelConfig, bundle: ArchBundle, *, impl: str = "xla",
             beta2=tc.beta2, weight_decay=tc.weight_decay,
             grad_clip=tc.grad_clip)
         new_state = TrainState(params, opt, state.step + 1, ef)
-        metrics = {"loss": loss, "grad_norm": gnorm, "lr": lr}
+        metrics = {"loss": loss, "grad_norm": gnorm, "lr": lr, "moe": moe}
         return new_state, metrics
 
     return train_step
@@ -101,12 +97,22 @@ class GrainAcc(NamedTuple):
     grads: Pytree
     loss_sum: jnp.ndarray
     n: jnp.ndarray             # grains accumulated
+    moe: Optional[MoEStats]    # MoE dispatch rows summed over grains;
+                               # None before the first grain
 
 
 def grain_acc_init(params: Pytree) -> GrainAcc:
     return GrainAcc(
         grads=jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params),
-        loss_sum=jnp.zeros(()), n=jnp.zeros((), jnp.int32))
+        loss_sum=jnp.zeros(()), n=jnp.zeros((), jnp.int32), moe=None)
+
+
+def _fold(acc: GrainAcc, loss, moe: MoEStats, grads) -> GrainAcc:
+    """acc with one grain's loss, MoE rows and gradient added."""
+    return GrainAcc(
+        grads=jax.tree.map(lambda a, g: a + g.astype(jnp.float32), acc.grads, grads),
+        loss_sum=acc.loss_sum + loss, n=acc.n + 1,
+        moe=moe if acc.moe is None else acc.moe.plus(moe))
 
 
 def make_grain_step(cfg: ModelConfig, bundle: ArchBundle, *, impl: str = "xla",
@@ -115,12 +121,9 @@ def make_grain_step(cfg: ModelConfig, bundle: ArchBundle, *, impl: str = "xla",
 
     def grain_step(params: Pytree, acc: GrainAcc,
                    grain: Dict[str, jnp.ndarray]) -> GrainAcc:
-        loss, grads = jax.value_and_grad(_loss_with_aux)(
-            params, grain, cfg, impl, remat)
-        return GrainAcc(
-            grads=jax.tree.map(lambda a, g: a + g.astype(jnp.float32),
-                               acc.grads, grads),
-            loss_sum=acc.loss_sum + loss, n=acc.n + 1)
+        (loss, moe), grads = jax.value_and_grad(loss_and_stats, has_aux=True)(
+            params, grain, cfg, impl=impl, remat=remat)
+        return _fold(acc, loss, moe, grads)
 
     return jax.jit(grain_step) if jit else grain_step
 
@@ -138,14 +141,12 @@ def make_grain_accumulate(cfg: ModelConfig, bundle: ArchBundle, *,
     def grain_accumulate(params: Pytree, acc: GrainAcc,
                          grains: Dict[str, jnp.ndarray]) -> GrainAcc:
         def body(carry: GrainAcc, grain: Dict[str, jnp.ndarray]):
-            loss, grads = jax.value_and_grad(_loss_with_aux)(
-                params, grain, cfg, impl, remat)
-            nxt = GrainAcc(
-                grads=jax.tree.map(lambda a, g: a + g.astype(jnp.float32),
-                                   carry.grads, grads),
-                loss_sum=carry.loss_sum + loss, n=carry.n + 1)
-            return nxt, None
+            (loss, moe), grads = jax.value_and_grad(loss_and_stats, has_aux=True)(
+                params, grain, cfg, impl=impl, remat=remat)
+            return _fold(carry, loss, moe, grads), None
 
+        if acc.moe is None:   # the scan's carry keeps one structure
+            acc = acc._replace(moe=MoEStats.zeros())
         out, _ = jax.lax.scan(body, acc, grains)
         return out
 
@@ -195,7 +196,8 @@ def make_apply_step(cfg: ModelConfig, bundle: ArchBundle, *,
             grads, state.opt, state.params, lr=lr, beta1=tc.beta1,
             beta2=tc.beta2, weight_decay=tc.weight_decay,
             grad_clip=tc.grad_clip)
-        metrics = {"loss": acc.loss_sum / denom, "grad_norm": gnorm, "lr": lr}
+        metrics = {"loss": acc.loss_sum / denom, "grad_norm": gnorm, "lr": lr,
+                   "moe": acc.moe}
         return TrainState(params, opt, state.step + 1, ef), metrics
 
     # donating acc lets its fp32 grads back the new fp32 moments

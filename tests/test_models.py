@@ -299,6 +299,61 @@ def test_moe_sort_dispatch_matches_dense_oracle():
     assert float(a1) == pytest.approx(float(a2))
 
 
+@pytest.mark.parametrize("batch", [1, 3], ids=["grouped", "capacity"])
+@pytest.mark.parametrize("skew", [0.0, 6.0], ids=["even", "skewed"])
+def test_moe_grouped_path_matches_dense_oracle(skew, batch):
+    """Where every expert can take every token, one sequence takes the
+    grouped dispatch and several the capacity path, and both give the dense
+    oracle's output, aux loss and gradients; ``skew`` makes expert 0 take
+    nearly every token."""
+    from repro.models.moe import (
+        is_dropless, moe_apply_dense_fallback, moe_init, moe_layer,
+        takes_grouped_path,
+    )
+    cfg = MoEConfig(n_experts=4, top_k=2, capacity_factor=2.0)
+    assert is_dropless(cfg, 16)
+    assert takes_grouped_path(cfg, batch, 16) == (batch == 1)
+    p = moe_init(KEY, 32, 64, cfg, glu=True, dtype=jnp.float32)
+    p["router"] = p["router"].at[:, 0].add(skew / 32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (batch, 16, 32)) + 1.0
+    ct = jax.random.normal(jax.random.PRNGKey(2), (batch, 16, 32))
+
+    def grouped(p, x):
+        out, aux, stats = moe_layer(p, x, cfg)
+        return jnp.sum(out * ct) + aux, (out, stats)
+
+    def oracle(p, x):
+        out, aux = moe_apply_dense_fallback(p, x, cfg)
+        return jnp.sum(out * ct) + aux, out
+
+    (l1, (o1, stats)), g1 = jax.value_and_grad(grouped, (0, 1), has_aux=True)(p, x)
+    (l2, o2), g2 = jax.value_and_grad(oracle, (0, 1), has_aux=True)(p, x)
+    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-4)
+    assert float(l1) == pytest.approx(float(l2), rel=1e-5)
+    for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4)
+    assert int(stats.routed_rows) == batch * 16 * 2 and int(stats.dropped_rows) == 0
+    if skew:   # expert 0, nearly every token
+        assert int(stats.max_expert_rows) >= 0.9 * batch * 16
+
+
+def test_moe_capacity_just_below_s_takes_the_capacity_path_and_drops():
+    from repro.models.moe import (
+        expert_capacities, is_dropless, moe_apply_dense_fallback, moe_init, moe_layer,
+    )
+    cfg = MoEConfig(n_experts=4, top_k=2, capacity_factor=1.8)
+    assert expert_capacities(cfg, 16).tolist() == [15] * 4 and not is_dropless(cfg, 16)
+    p = moe_init(KEY, 32, 64, cfg, glu=True, dtype=jnp.float32)
+    p["router"] = p["router"].at[:, 0].add(16.0 / 32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (3, 16, 32)) + 1.0
+    out, _, stats = moe_layer(p, x, cfg)
+    # expert 0 is every token's choice: one row past its 15 slots a sequence
+    assert int(stats.dropped_rows) == 3 and int(stats.routed_rows) == 96
+    assert int(stats.max_expert_rows) == 3 * 16
+    want, _ = moe_apply_dense_fallback(p, x, cfg)
+    assert not np.allclose(np.asarray(out), np.asarray(want), atol=1e-4)
+
+
 def test_pad_vocab_loss_exactness():
     """Pad-vocab logits must not leak probability mass into the loss."""
     arch = "granite-3-8b"          # 49155 -> padded 49408
@@ -372,7 +427,7 @@ def test_chunked_xent_matches_dense():
             del os.environ["REPRO_DENSE_XENT"]
 
     def f_chunk(p):
-        x, aux = hidden_states(p, batch["tokens"], cfg)
+        x, aux, _ = hidden_states(p, batch["tokens"], cfg)
         nll = chunked_softmax_xent(x, p["embed"]["table"], batch["labels"],
                                    cfg.vocab_size, chunk=256)
         return jnp.mean(nll) + aux

@@ -147,3 +147,62 @@ def test_run_step_spans_and_no_recompiles_in_steady_state():
     c = telemetry.summary()["counters"]
     assert c["train.steps"] == 3 and c["train.grains"] == 3 * 4
     assert c["train.staged_bytes"] == 3 * 2 * 8 * 16 * 4     # tokens + labels, int32
+
+
+@pytest.mark.parametrize("capacity_factor,batch,path", [
+    (4.0, 1, "grouped"), (4.0, 2, "capacity"), (1.25, 1, "capacity")])
+def test_moe_layers_are_counted_by_dispatch_path(capacity_factor, batch, path):
+    """Tracing a stack counts its MoE layers by the path their calls take:
+    grouped for one sequence that every expert can take whole, else the
+    capacity path; running the traced program counts nothing more."""
+    from repro.models.model import init_params, loss_fn
+    base = get_reduced("granite-moe-1b-a400m")
+    cfg = dataclasses.replace(base, moe=dataclasses.replace(
+        base.moe, capacity_factor=capacity_factor))
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    rows = {"tokens": jnp.ones((batch, 16), jnp.int32),
+            "labels": jnp.ones((batch, 16), jnp.int32)}
+    f = jax.jit(lambda p: loss_fn(p, rows, cfg))
+    telemetry.reset()
+    f(params)
+    c = telemetry.summary()["counters"]
+    other = "capacity" if path == "grouped" else "grouped"
+    assert c[f"moe.layers_{path}"] == cfg.n_layers
+    assert f"moe.layers_{other}" not in c
+    f(params)
+    assert telemetry.summary()["counters"] == c
+
+
+def test_run_step_reads_back_the_moe_rows_of_each_step():
+    """On the grouped path (grains of one sequence) every routed row is
+    kept: per step the trainer records S*k rows a layer and grain, none
+    dropped, at the step's wait, and the largest group at least the mean
+    one."""
+    base = get_reduced("granite-moe-1b-a400m")
+    cfg = dataclasses.replace(base, moe=dataclasses.replace(
+        base.moe, capacity_factor=float(base.moe.n_experts // base.moe.top_k)))
+    bundle = ArchBundle(model=cfg, train=TrainConfig(lr=1e-3, warmup_steps=2,
+                                                     total_steps=50))
+    tr = HeMTTrainer(cfg, bundle, [SliceSpec("s0"), SliceSpec("s1", [(0.0, 0.5)])],
+                     grain_batch=1, global_batch=8, seq_len=16, mode="hemt")
+    state = train_state_init(jax.random.PRNGKey(0), cfg, bundle)
+    for _ in range(2):
+        state, _ = tr.run_step(state)
+    k, e, layers, grains = cfg.moe.top_k, cfg.moe.n_experts, cfg.n_layers, 8
+    routed = 16 * k * layers * grains
+    recs = [r for r in telemetry.records() if r.name.startswith("moe.")]
+    assert {(r.name, r.step) for r in recs} == {
+        (f"moe.{n}", s) for n in ("routed_rows", "dropped_rows", "max_expert_rows")
+        for s in (0, 1)}
+    for r in recs:
+        assert r.parent == "repro.train.step" and r.t0_ns == r.t1_ns
+        if r.name == "moe.routed_rows":
+            assert r.value == routed
+        elif r.name == "moe.dropped_rows":
+            assert r.value == 0
+        else:
+            assert routed // e <= r.value <= routed // k
+    c = telemetry.summary()
+    assert c["counters"]["moe.routed_rows"] == 2 * routed
+    assert c["counters"]["moe.dropped_rows"] == 0
+    assert not [n for n in c["spans"] if n.startswith("moe.")]

@@ -171,3 +171,76 @@ def test_granite_decode_step_writes_one_token_in_place(one_chip):
     cache_bytes = sum(a.size * a.dtype.itemsize
                       for a in jax.tree.leaves(state["cache"]))
     assert m.alias_size_in_bytes >= cache_bytes
+
+
+def test_moe_grouped_dispatch_compiles_at_granite_moe_widths(one_chip):
+    """granite-moe-1b-a400m's expert layer as the training cell runs it (one
+    sequence of 4096 tokens, 32 experts of 512, top 8, every expert able to
+    take every token): forward and backward compile for the chip as grouped
+    products, and no (B, E*cap, D) slot buffer is materialized."""
+    from repro.configs.base import MoEConfig
+    from repro.models.moe import is_dropless, moe_layer
+    s, d, f, e, k = 4096, 1024, 512, 32, 8
+    cfg = MoEConfig(n_experts=e, top_k=k, capacity_factor=e / k)
+    assert is_dropless(cfg, s)
+    p = {"router": jax.ShapeDtypeStruct((d, e), jnp.float32),
+         "w_gate": jax.ShapeDtypeStruct((e, d, f), jnp.bfloat16),
+         "w_up": jax.ShapeDtypeStruct((e, d, f), jnp.bfloat16),
+         "w_down": jax.ShapeDtypeStruct((e, f, d), jnp.bfloat16)}
+    x = jax.ShapeDtypeStruct((1, s, d), jnp.bfloat16)
+
+    def loss(p, x):
+        out, aux, _ = moe_layer(p, x, cfg)
+        return jnp.sum(out.astype(jnp.float32)) + aux
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        *_on(one_chip, (p, x))).compile().as_text()
+    assert "ragged-dot" in hlo
+    slot_buffer = e * s * d                    # capacity S per expert
+    assert not _materialized(hlo, slot_buffer)
+    assert _materialized(hlo, s * k * d)       # the sorted rows are there
+
+
+_COLLECTIVE = re.compile(r"= \w+\[([\d,]*)\]\S* (all-gather|all-reduce|all-to-all|"
+                         r"collective-permute|reduce-scatter)(?:-start)?\(")
+
+
+@pytest.mark.parametrize("batch,seq,capacity_factor", [(4, 4096, 4.0), (32, 1, 1.25)],
+                         ids=["train", "decode"])
+def test_moe_layer_keeps_rows_in_their_data_shard(batch, seq, capacity_factor):
+    """granite-moe-1b-a400m's expert layer with its batch split over the
+    four chips of a described v5e:2x2 and the weights on every chip: a
+    training call (forward and backward) and a decode step, both with no
+    choice dropped. A call of several sequences sorts each on its own, so
+    no collective moves a shard's routed rows."""
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.configs.base import MoEConfig
+    from repro.models.moe import is_dropless, moe_layer
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("data",))
+    d, f, e, k = 1024, 512, 32, 8
+    cfg = MoEConfig(n_experts=e, top_k=k, capacity_factor=capacity_factor)
+    assert is_dropless(cfg, seq)
+    everywhere = NamedSharding(mesh, P())
+    p = {"router": jax.ShapeDtypeStruct((d, e), jnp.float32, sharding=everywhere),
+         **{n: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=everywhere)
+            for n, shape in (("w_gate", (e, d, f)), ("w_up", (e, d, f)),
+                             ("w_down", (e, f, d)))}}
+    x = jax.ShapeDtypeStruct((batch, seq, d), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("data")))
+
+    def loss(p, x):
+        out, aux, _ = moe_layer(p, x, cfg)
+        return jnp.sum(out.astype(jnp.float32)) + aux
+
+    fn = jax.grad(loss, argnums=(0, 1)) if seq > 1 else (
+        lambda p, x: moe_layer(p, x, cfg)[0])
+    hlo = jax.jit(fn).lower(p, x).compile().as_text()
+    shard_rows = batch // 4 * seq * k * d
+    moved = [(op, dims) for dims, op in _COLLECTIVE.findall(hlo)
+             if math.prod(int(n) for n in dims.split(",") if n) >= shard_rows]
+    assert not moved, moved
