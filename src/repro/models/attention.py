@@ -6,6 +6,8 @@ same math (selected via ``impl='pallas'``).
 """
 from __future__ import annotations
 
+import functools
+import itertools
 import math
 from typing import Dict, Optional, Tuple
 
@@ -59,6 +61,19 @@ def dot_product_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         return out.reshape(b, sq, hq, dh)
 
 
+def _open_key_blocks(i: int, bq: int, bk: int, nk: int, sq: int, *,
+                     causal: bool, window: int) -> Tuple[int, int]:
+    """Key blocks ``[lo, hi)`` that hold a position some query of query
+    block ``i`` may see. Query ``r`` sees keys ``r - window < p <= r`` (each
+    bound where it applies), so the keys open to the block's rows
+    ``first..last`` form one run, from ``first - window + 1`` to ``last``:
+    every key block between the first and the last open one is open too."""
+    first, last = i * bq, min((i + 1) * bq, sq) - 1
+    lo = max(0, (first - window + 1) // bk) if window > 0 else 0
+    hi = min(nk, last // bk + 1) if causal else nk
+    return lo, max(lo, hi)
+
+
 def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                       causal: bool, window: int, scale: float,
                       block_q: int = 512, block_k: int = 1024) -> jnp.ndarray:
@@ -68,8 +83,19 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     ``dot_product_attention`` with arange positions; the Pallas kernel
     (`repro.kernels.flash_attention`) is the TPU-native twin.
 
+    Only the (query block, key block) tiles in which the mask leaves some
+    position open are computed, forward and backward: each query block
+    scans just the run of key blocks that ``_open_key_blocks`` gives it.
+    A closed tile changes nothing: after a row's first open key it adds
+    exact zeros (``p = 0``, ``alpha = 1``), and what it adds before that
+    key is scaled away there (``alpha = 0``). So the result is the same
+    arithmetic on the tiles computed. The schedule is static; tracing
+    counts ``attention.tiles_computed`` and ``attention.tiles_skipped``.
+
     q: (B, Sq, Hq, D); k/v: (B, Sk, Hkv, D) -> (B, Sq, Hq, D).
     """
+    from repro.runtime import telemetry
+
     b, sq, hq, d = q.shape
     sk, hkv = k.shape[1], k.shape[2]
     g = hq // hkv
@@ -88,8 +114,14 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     kb = k.reshape(b, nk, bk, hkv, d).transpose(1, 0, 3, 2, 4)
     vb = v.reshape(b, nk, bk, hkv, d).transpose(1, 0, 3, 2, 4)
 
-    def q_block(args):
-        qi, qt = args                                     # qt (B,Hkv,g,bq,D)
+    spans = [_open_key_blocks(i, bq, bk, nk, sq, causal=causal, window=window)
+             for i in range(nq)]
+    computed = sum(hi - lo for lo, hi in spans)
+    telemetry.count("attention.tiles_computed", computed)
+    telemetry.count("attention.tiles_skipped", nq * nk - computed)
+
+    def q_block(args, n_kv):
+        qi, lo, qt = args                                 # qt (B,Hkv,g,bq,D)
         q0 = qi * bq
 
         def kv_step(carry, inp):
@@ -124,16 +156,28 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         init = (jnp.full((b, hkv, g, bq, 1), NEG_INF, jnp.float32),
                 jnp.zeros((b, hkv, g, bq, 1), jnp.float32),
                 jnp.zeros((b, hkv, g, bq, d), jnp.float32))
-        (m, l, acc), _ = jax.lax.scan(kv_step, init,
-                                      (jnp.arange(nk), kb, vb))
-        return acc / jnp.where(l == 0.0, 1.0, l)
+        kv = (lo + jnp.arange(n_kv), jax.lax.dynamic_slice_in_dim(kb, lo, n_kv),
+              jax.lax.dynamic_slice_in_dim(vb, lo, n_kv))
+        (m, l, acc), _ = jax.lax.scan(kv_step, init, kv)
+        # in q's dtype here: the groups' outputs are copied once more by
+        # the concatenation below
+        return (acc / jnp.where(l == 0.0, 1.0, l)).astype(q.dtype)
 
-    q_block = jax.checkpoint(
-        q_block, policy=jax.checkpoint_policies.nothing_saveable)
+    # consecutive query blocks that scan equally many key blocks share one
+    # lax.map: a causal mask makes one map per length, no mask a single map
+    outs = []
     with jax.named_scope("attention_core"):
-        out = jax.lax.map(q_block, (jnp.arange(nq), qb))  # (nq,B,Hkv,g,bq,D)
+        for n_kv, group in itertools.groupby(
+                range(nq), key=lambda i: spans[i][1] - spans[i][0]):
+            idx = list(group)
+            i0, i1 = idx[0], idx[-1] + 1
+            fn = jax.checkpoint(functools.partial(q_block, n_kv=n_kv),
+                                policy=jax.checkpoint_policies.nothing_saveable)
+            lo = jnp.array([spans[i][0] for i in idx], jnp.int32)
+            outs.append(jax.lax.map(fn, (jnp.arange(i0, i1), lo, qb[i0:i1])))
+    out = jnp.concatenate(outs)                           # (nq,B,Hkv,g,bq,D)
     out = out.transpose(1, 0, 4, 2, 3, 5).reshape(b, sq_p, hq, d)
-    return out[:, :sq].astype(q.dtype)
+    return out[:, :sq]
 
 
 # sequences at or above this length stream through chunked_attention

@@ -237,40 +237,120 @@ def test_decode_step_matches_sequence_major_oracle(arch, max_len, steps):
                                    rtol=1e-5, atol=1e-5)
 
 
-def test_chunked_attention_equals_dense():
+def _oracle_chunked_attention(q, k, v, *, causal, window, scale, block_q,
+                              block_k):
+    """The streaming attention as it was before tiles were skipped: every
+    query block scans every key block and the mask zeroes the closed ones."""
+    b, sq, hq, d = q.shape
+    sk, hkv = k.shape[1], k.shape[2]
+    g = hq // hkv
+    bq, bk = min(block_q, sq), min(block_k, sk)
+    sq_p, sk_p = -(-sq // bq) * bq, -(-sk // bk) * bk
+    q = jnp.pad(q, ((0, 0), (0, sq_p - sq), (0, 0), (0, 0)))
+    k = jnp.pad(k, ((0, 0), (0, sk_p - sk), (0, 0), (0, 0)))
+    v = jnp.pad(v, ((0, 0), (0, sk_p - sk), (0, 0), (0, 0)))
+    nq, nk = sq_p // bq, sk_p // bk
+    qb = q.reshape(b, nq, bq, hkv, g, d).transpose(1, 0, 3, 4, 2, 5)
+    kb = k.reshape(b, nk, bk, hkv, d).transpose(1, 0, 3, 2, 4)
+    vb = v.reshape(b, nk, bk, hkv, d).transpose(1, 0, 3, 2, 4)
+
+    def q_block(args):
+        qi, qt = args
+
+        def kv_step(carry, inp):
+            m_p, l_p, acc = carry
+            ki, kt, vt = inp
+            s = jnp.einsum("bhgqd,bhkd->bhgqk", qt.astype(jnp.float32),
+                           kt.astype(jnp.float32)) * scale
+            qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+            kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            ok = kpos < sk
+            if causal:
+                ok &= qpos - kpos >= 0
+            if window > 0:
+                ok &= qpos - kpos < window
+            s = jnp.where(ok[None, None, None], s, -1e30)
+            m_n = jnp.maximum(m_p, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_n)
+            alpha = jnp.exp(m_p - m_n)
+            l_n = l_p * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc * alpha + jnp.einsum("bhgqk,bhkd->bhgqd", p,
+                                           vt.astype(jnp.float32))
+            return (m_n, l_n, acc), None
+
+        kv_step = jax.checkpoint(
+            kv_step, policy=jax.checkpoint_policies.nothing_saveable)
+        init = (jnp.full((b, hkv, g, bq, 1), -1e30, jnp.float32),
+                jnp.zeros((b, hkv, g, bq, 1), jnp.float32),
+                jnp.zeros((b, hkv, g, bq, d), jnp.float32))
+        (m, l, acc), _ = jax.lax.scan(kv_step, init, (jnp.arange(nk), kb, vb))
+        return acc / jnp.where(l == 0.0, 1.0, l)
+
+    q_block = jax.checkpoint(
+        q_block, policy=jax.checkpoint_policies.nothing_saveable)
+    out = jax.lax.map(q_block, (jnp.arange(nq), qb))
+    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(b, sq_p, hq, d)
+    return out[:, :sq].astype(q.dtype)
+
+
+# (sq, causal, window, block_q, block_k)
+CHUNKED_CASES = {
+    "causal-bk<bq": (80, True, 0, 32, 16),
+    "causal-bk>bq": (64, True, 0, 16, 32),
+    "window<block": (80, True, 5, 32, 16),
+    "window17": (80, True, 17, 32, 16),
+    "window-spans-blocks": (80, True, 40, 16, 32),
+    "noncausal": (80, False, 0, 32, 16),
+    "noncausal-window": (80, False, 9, 16, 32),
+    "sq-not-a-multiple": (72, True, 0, 32, 16),
+}
+
+
+def _chunked_case(case, batch, hq, hkv):
+    sq, causal, win, bq, bk = CHUNKED_CASES[case]
     ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (2, 80, 4, 16))
-    k = jax.random.normal(ks[1], (2, 80, 2, 16))
-    v = jax.random.normal(ks[2], (2, 80, 2, 16))
-    pos = jnp.broadcast_to(jnp.arange(80)[None], (2, 80))
-    for causal, win in [(True, 0), (True, 17), (False, 0)]:
-        want = dot_product_attention(q, k, v,
-                                     _mask_bias(pos, pos, causal, win), 0.25)
-        got = chunked_attention(q, k, v, causal=causal, window=win,
-                                scale=0.25, block_q=32, block_k=16)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   atol=2e-5)
+    q = jax.random.normal(ks[0], (batch, sq, hq, 16))
+    k = jax.random.normal(ks[1], (batch, sq, hkv, 16))
+    v = jax.random.normal(ks[2], (batch, sq, hkv, 16))
+    pos = jnp.broadcast_to(jnp.arange(sq)[None], (batch, sq))
+    kw = dict(causal=causal, window=win, scale=0.25, block_q=bq, block_k=bk)
+
+    def dense(q, k, v):
+        return dot_product_attention(q, k, v, _mask_bias(pos, pos, causal, win),
+                                     0.25)
+    return (q, k, v), kw, dense
 
 
-def test_chunked_attention_gradients_match():
-    ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (1, 64, 2, 16))
-    k = jax.random.normal(ks[1], (1, 64, 2, 16))
-    v = jax.random.normal(ks[2], (1, 64, 2, 16))
-    pos = jnp.broadcast_to(jnp.arange(64)[None], (1, 64))
+@pytest.mark.parametrize("case", list(CHUNKED_CASES))
+def test_chunked_attention_equals_dense(case):
+    """The tile-skipping streaming attention gives what visiting every tile
+    gave, and what the dense masked softmax gives."""
+    (q, k, v), kw, dense = _chunked_case(case, 2, 4, 2)
+    got = jax.jit(lambda q, k, v: chunked_attention(q, k, v, **kw))(q, k, v)
+    want = jax.jit(lambda q, k, v: _oracle_chunked_attention(q, k, v, **kw))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want(q, k, v)),
+                               atol=1e-6)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(dense(q, k, v)),
+                               atol=2e-5)
 
-    def f_dense(q, k, v):
-        return jnp.sum(dot_product_attention(
-            q, k, v, _mask_bias(pos, pos, True, 0), 0.25) ** 2)
 
-    def f_chunk(q, k, v):
-        return jnp.sum(chunked_attention(
-            q, k, v, causal=True, window=0, scale=0.25,
-            block_q=16, block_k=32) ** 2)
+@pytest.mark.parametrize("case", list(CHUNKED_CASES))
+def test_chunked_attention_gradients_match(case):
+    """Gradients through the skipped tiles' schedule: against the oracle
+    that visits every tile, and against the dense masked softmax."""
+    (q, k, v), kw, dense = _chunked_case(case, 1, 2, 2)
 
-    g1 = jax.grad(f_dense)(q, k, v)
-    g2 = jax.grad(f_chunk)(q, k, v)
-    np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), atol=3e-4)
+    def loss(f):
+        return jax.jit(jax.grad(lambda q, k, v: jnp.sum(f(q, k, v) ** 2),
+                                (0, 1, 2)))
+
+    g = loss(lambda q, k, v: chunked_attention(q, k, v, **kw))(q, k, v)
+    g_oracle = loss(lambda q, k, v: _oracle_chunked_attention(q, k, v, **kw))(
+        q, k, v)
+    g_dense = loss(dense)(q, k, v)
+    for a, b, c in zip(g, g_oracle, g_dense):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=3e-4)
 
 
 def test_moe_capacity_skew_shifts_tokens():

@@ -103,6 +103,37 @@ def test_decode_step_counts_cache_layers_by_kind(arch, in_place, copied):
     assert telemetry.summary()["counters"] == c
 
 
+@pytest.mark.parametrize("causal,window", [(True, 0), (True, 700), (False, 0)],
+                         ids=["causal", "window", "noncausal"])
+def test_chunked_attention_counts_the_tiles_its_mask_leaves_open(causal, window):
+    """Tracing chunked_attention at S = 4096 with its own block sizes counts
+    as computed exactly the (query block, key block) tiles in which a
+    brute-force pass over the mask finds an open position, the rest as
+    skipped."""
+    import inspect
+
+    import numpy as np
+
+    from repro.models.attention import chunked_attention
+    s = 4096
+    blocks = inspect.signature(chunked_attention).parameters
+    bq, bk = blocks["block_q"].default, blocks["block_k"].default
+    pos = np.arange(s)
+    rel = pos[:, None] - pos[None, :]
+    ok = np.ones((s, s), bool)
+    if causal:
+        ok &= rel >= 0
+    if window:
+        ok &= rel < window
+    open_tiles = int(ok.reshape(s // bq, bq, s // bk, bk).any(axis=(1, 3)).sum())
+    x = jax.ShapeDtypeStruct((1, s, 2, 8), jnp.float32)
+    jax.eval_shape(lambda q, k, v: chunked_attention(
+        q, k, v, causal=causal, window=window, scale=1.0), x, x, x)
+    c = telemetry.summary()["counters"]
+    assert c["attention.tiles_computed"] == open_tiles
+    assert c["attention.tiles_skipped"] == (s // bq) * (s // bk) - open_tiles
+
+
 def test_a_fresh_jit_compiles_once():
     f = jax.jit(lambda x: jnp.sin(x) * 3.0 + 1.0)
     x, y = jnp.arange(8.0), jnp.ones(8)

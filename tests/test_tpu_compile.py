@@ -201,6 +201,33 @@ def test_moe_grouped_dispatch_compiles_at_granite_moe_widths(one_chip):
     assert _materialized(hlo, s * k * d)       # the sorted rows are there
 
 
+# grain_accumulate's temporary bytes for the MoE training cell when the
+# chunked attention visited every (query block, key block) tile
+MOE_GRAIN_TEMP_EVERY_TILE = 2_656_546_816
+
+
+def test_granite_moe_grain_accumulate_compiles_in_no_more_temp(one_chip):
+    """granite-moe-1b-a400m's grain_accumulate as its training cell runs it
+    (12 layers at published widths, dropless, one sequence of 4096 tokens,
+    whose attention takes the chunked path) compiles for one chip, and
+    computing only attention's open tiles takes no more temporary memory
+    than visiting every tile did."""
+    from repro.configs import get_bundle
+    from repro.runtime.train_loop import grain_acc_init, make_grain_accumulate
+    base = get_config("granite-moe-1b-a400m")
+    cfg = dataclasses.replace(base, n_layers=12, moe=dataclasses.replace(
+        base.moe, capacity_factor=base.moe.n_experts / base.moe.top_k))
+    bundle = get_bundle("granite-moe-1b-a400m").replace(model=cfg)
+    params = jax.eval_shape(lambda k: init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    acc = jax.eval_shape(grain_acc_init, params)
+    rows = jax.ShapeDtypeStruct((1, 1, 4096), jnp.int32)
+    compiled = make_grain_accumulate(cfg, bundle).lower(*_on(
+        one_chip, (params, acc, {"tokens": rows, "labels": rows}))).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= MOE_GRAIN_TEMP_EVERY_TILE, temp
+
+
 _COLLECTIVE = re.compile(r"= \w+\[([\d,]*)\]\S* (all-gather|all-reduce|all-to-all|"
                          r"collective-permute|reduce-scatter)(?:-start)?\(")
 
