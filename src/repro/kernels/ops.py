@@ -5,6 +5,10 @@ for the Pallas interpreter with ``interpret=True``; without it the call
 fails instead of quietly running interpreted. The wrappers do the layout
 transposes + padding and the cheap elementwise prep that XLA fuses with
 neighbouring ops.
+
+No model calls ``flash_attention`` or ``ssd_scan``: they are standalone,
+checked against ``repro.kernels.ref``, until a chip measurement puts one
+on the model's path.
 """
 from __future__ import annotations
 

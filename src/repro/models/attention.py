@@ -1,8 +1,9 @@
 """Attention: GQA, causal / sliding-window masks, cross-attention, KV cache.
 
-The XLA path (`dot_product_attention`) is the default for lowering/dry-run;
-`repro.kernels.ops.flash_attention` provides the Pallas TPU kernel for the
-same math (selected via ``impl='pallas'``).
+Self-attention takes the dense path (`dot_product_attention`) for short
+sequences and streams through `chunked_attention` from
+``CHUNKED_THRESHOLD`` on; training, prefill and decode share the
+projections.
 """
 from __future__ import annotations
 
@@ -80,8 +81,7 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     """Flash-equivalent streaming attention in pure XLA (lax.scan online
     softmax) — the compile target for long sequences where the dense
     (Sq x Sk) logits tensor must never materialize. Same math as
-    ``dot_product_attention`` with arange positions; the Pallas kernel
-    (`repro.kernels.flash_attention`) is the TPU-native twin.
+    ``dot_product_attention`` with arange positions.
 
     Only the (query block, key block) tiles in which the mask leaves some
     position open are computed, forward and backward: each query block
@@ -184,79 +184,70 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 CHUNKED_THRESHOLD = 2048
 
 
-def attention_apply(params: Params, x: jnp.ndarray, cfg: AttentionConfig,
-                    positions: jnp.ndarray, *, window_override: Optional[int] = None,
-                    kv_source: Optional[jnp.ndarray] = None,
-                    impl: str = "xla") -> jnp.ndarray:
-    """Full-sequence attention (train / prefill).
+def _scale(cfg: AttentionConfig) -> float:
+    return cfg.scale if cfg.scale is not None else 1.0 / math.sqrt(cfg.head_dim)
 
-    kv_source: if given, keys/values come from it (cross-attention, no mask,
-    no rope on kv beyond source positions).
-    """
-    b, s, _ = x.shape
+
+def _project(params: Params, x: jnp.ndarray, src: jnp.ndarray,
+             cfg: AttentionConfig, positions: Optional[jnp.ndarray] = None,
+             ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """q (B, Sq, Hq, Dh) of x; k, v (B, Sk, Hkv, Dh) of src. q and k are
+    rotated to ``positions`` where they are given (self-attention)."""
+    b = x.shape[0]
     dh, hq, hkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    q = (x @ params["wq"]).reshape(b, s, hq, dh)
-
-    cross = kv_source is not None
-    src = kv_source if cross else x
-    sk = src.shape[1]
-    k = (src @ params["wk"]).reshape(b, sk, hkv, dh)
-    v = (src @ params["wv"]).reshape(b, sk, hkv, dh)
-
-    if not cross:
+    q = (x @ params["wq"]).reshape(b, x.shape[1], hq, dh)
+    k = (src @ params["wk"]).reshape(b, src.shape[1], hkv, dh)
+    v = (src @ params["wv"]).reshape(b, src.shape[1], hkv, dh)
+    if positions is not None:
         q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_style)
         k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_style)
-        window = cfg.sliding_window if window_override is None else window_override
-        bias = _mask_bias(positions, positions, cfg.causal, window)
-    else:
-        bias = None
+    return q, k, v
 
-    scale = cfg.scale if cfg.scale is not None else 1.0 / math.sqrt(dh)
-    if impl == "pallas" and not cross:
-        from repro.kernels import ops as kops
-        window = cfg.sliding_window if window_override is None else window_override
-        out = kops.flash_attention(q, k, v, causal=cfg.causal, window=window,
-                                   scale=scale)
-    elif not cross and (impl == "chunked" or max(s, sk) >= CHUNKED_THRESHOLD):
-        window = cfg.sliding_window if window_override is None else window_override
-        out = chunked_attention(q, k, v, causal=cfg.causal, window=window,
-                                scale=scale)
-    else:
-        out = dot_product_attention(q, k, v, bias, scale)
-    return out.reshape(b, s, hq * dh) @ params["wo"]
+
+def _attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *, causal: bool,
+            window: int, scale: float) -> jnp.ndarray:
+    """Self-attention over arange positions: chunked from
+    ``CHUNKED_THRESHOLD`` on, dense below it."""
+    sq, sk = q.shape[1], k.shape[1]
+    if max(sq, sk) >= CHUNKED_THRESHOLD:
+        return chunked_attention(q, k, v, causal=causal, window=window,
+                                 scale=scale)
+    bias = _mask_bias(jnp.arange(sq), jnp.arange(sk), causal, window)
+    return dot_product_attention(q, k, v, bias[None], scale)
+
+
+def attention_apply(params: Params, x: jnp.ndarray, cfg: AttentionConfig,
+                    positions: jnp.ndarray, *, window: int) -> jnp.ndarray:
+    """Full-sequence self-attention (train). ``window`` is the layer's
+    lookback, 0 for none (``transformer.layer_window``)."""
+    b, s, _ = x.shape
+    q, k, v = _project(params, x, x, cfg, positions)
+    out = _attend(q, k, v, causal=cfg.causal, window=window, scale=_scale(cfg))
+    return out.reshape(b, s, -1) @ params["wo"]
+
+
+def cross_attention(params: Params, x: jnp.ndarray, enc_out: jnp.ndarray,
+                    cfg: AttentionConfig) -> jnp.ndarray:
+    """x attends to the encoder's output: no mask, no rope, dense."""
+    b, s, _ = x.shape
+    q, k, v = _project(params, x, enc_out, cfg)
+    out = dot_product_attention(q, k, v, None, _scale(cfg))
+    return out.reshape(b, s, -1) @ params["wo"]
 
 
 def attention_prefill(params: Params, x: jnp.ndarray, cfg: AttentionConfig,
-                      positions: jnp.ndarray, cache_len: int, *,
-                      window_override: Optional[int] = None, impl: str = "xla",
+                      positions: jnp.ndarray, cache_len: int, *, window: int,
                       ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Full-sequence self-attention that also emits the decode KV cache.
 
-    Returns (out (B,S,D), cache {"k","v"} of (B, cache_len, Hkv, Dh)) laid
+    Returns (out (B,S,D), cache {"k","v"} of (B, Hkv, cache_len, Dh)) laid
     out ring-buffer style: slot i holds the largest position p < S with
     p % cache_len == i (matches attention_decode_step's addressing).
     """
     b, s, _ = x.shape
-    dh, hq, hkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    q = (x @ params["wq"]).reshape(b, s, hq, dh)
-    k = (x @ params["wk"]).reshape(b, s, hkv, dh)
-    v = (x @ params["wv"]).reshape(b, s, hkv, dh)
-    q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_style)
-    k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_style)
-
-    window = cfg.sliding_window if window_override is None else window_override
-    scale = cfg.scale if cfg.scale is not None else 1.0 / math.sqrt(dh)
-    if impl == "pallas":
-        from repro.kernels import ops as kops
-        out = kops.flash_attention(q, k, v, causal=cfg.causal, window=window,
-                                   scale=scale)
-    elif impl == "chunked" or s >= CHUNKED_THRESHOLD:
-        out = chunked_attention(q, k, v, causal=cfg.causal, window=window,
-                                scale=scale)
-    else:
-        bias = _mask_bias(positions, positions, cfg.causal, window)
-        out = dot_product_attention(q, k, v, bias, scale)
-    out = out.reshape(b, s, hq * dh) @ params["wo"]
+    q, k, v = _project(params, x, x, cfg, positions)
+    out = _attend(q, k, v, causal=cfg.causal, window=window, scale=_scale(cfg))
+    out = out.reshape(b, s, -1) @ params["wo"]
 
     # ring-layout fill: slot i <- position p = s-1 - ((s-1-i) mod cap), p>=0
     cap = cache_len
@@ -286,8 +277,7 @@ def init_kv_cache(batch: int, max_len: int, cfg: AttentionConfig,
 
 def attention_decode_step(params: Params, x: jnp.ndarray, cache: Dict[str, jnp.ndarray],
                           layer: jnp.ndarray, cache_len: jnp.ndarray,
-                          cfg: AttentionConfig, *,
-                          window_override: Optional[int] = None,
+                          cfg: AttentionConfig, *, window: int,
                           ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """One-token decode. x: (B, 1, D); cache_len: scalar int32 (current length).
 
@@ -303,11 +293,7 @@ def attention_decode_step(params: Params, x: jnp.ndarray, cache: Dict[str, jnp.n
     cap = cache["k"].shape[3]
 
     pos = jnp.full((b, 1), cache_len, jnp.int32)
-    q = (x @ params["wq"]).reshape(b, 1, hq, dh)
-    q = apply_rope(q, pos, cfg.rope_theta, cfg.rope_style)
-    k_new = (x @ params["wk"]).reshape(b, 1, hkv, dh)
-    k_new = apply_rope(k_new, pos, cfg.rope_theta, cfg.rope_style)
-    v_new = (x @ params["wv"]).reshape(b, 1, hkv, dh)
+    q, k_new, v_new = _project(params, x, x, cfg, pos)
 
     # The token is written in the cache's own (row-major) layout. Without
     # the constraint XLA gives V's write the projection's layout and
@@ -330,16 +316,14 @@ def attention_decode_step(params: Params, x: jnp.ndarray, cache: Dict[str, jnp.n
     idx = jnp.arange(cap)
     abs_pos = cache_len - jnp.mod(cache_len - idx, cap)
     valid = abs_pos >= 0
-    window = cfg.sliding_window if window_override is None else window_override
     if window > 0:
         valid &= (cache_len - abs_pos) < window
     bias = jnp.where(valid, 0.0, NEG_INF)                 # (cap,)
 
-    scale = cfg.scale if cfg.scale is not None else 1.0 / math.sqrt(dh)
     with jax.named_scope("attention_core"):
         qg = q.reshape(b, hkv, hq // hkv, dh)
         logits = jnp.einsum("bhgd,bhkd->bhgk", qg.astype(jnp.float32),
-                            k.astype(jnp.float32)) * scale + bias
+                            k.astype(jnp.float32)) * _scale(cfg) + bias
         probs = jax.nn.softmax(logits, axis=-1)
         out = jnp.einsum("bhgk,bhkd->bhgd", probs.astype(v.dtype), v)
     out = out.reshape(b, 1, hq * dh) @ params["wo"]

@@ -5,7 +5,6 @@ ShapeDtypeStruct pytrees for 100B+ configs without allocating.
 """
 from __future__ import annotations
 
-import os
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -81,7 +80,7 @@ def _encoder_cfg(cfg: ModelConfig) -> ModelConfig:
 
 
 def encode(params: Pytree, enc_feats: jnp.ndarray, cfg: ModelConfig, *,
-           impl: str = "xla", remat: str = "none") -> jnp.ndarray:
+           remat: str = "none") -> jnp.ndarray:
     """Whisper-style encoder over precomputed (stub) frame embeddings."""
     enc_cfg = _encoder_cfg(cfg)
     x = frontends.adapter_apply(params["adapter"], enc_feats) \
@@ -90,18 +89,15 @@ def encode(params: Pytree, enc_feats: jnp.ndarray, cfg: ModelConfig, *,
     x = x + sinusoidal_positions(s, cfg.d_model)[None].astype(x.dtype)
     pos = jnp.broadcast_to(jnp.arange(s)[None], x.shape[:2])
     x, _, _ = transformer.stack_apply(params["encoder"], x, enc_cfg, pos,
-                                      causal=False, impl=impl, remat=remat)
+                                      causal=False, remat=remat)
     return rmsnorm(params["enc_norm"], x, cfg.norm_eps)
 
 
-def hidden_states(params: Pytree, tokens: Optional[jnp.ndarray],
-                  cfg: ModelConfig, *,
-                  input_embeds: Optional[jnp.ndarray] = None,
-                  enc_feats: Optional[jnp.ndarray] = None,
-                  impl: str = "xla", remat: str = "none", constrain=None,
-                  ) -> Tuple[jnp.ndarray, jnp.ndarray, MoEStats]:
-    """Final-norm hidden states (B,S,D) (pre-unembed), the moe aux loss and
-    the moe dispatch's row counts."""
+def _inputs(params: Pytree, tokens: Optional[jnp.ndarray], cfg: ModelConfig,
+            input_embeds: Optional[jnp.ndarray], enc_feats: Optional[jnp.ndarray],
+            remat: str) -> Tuple[jnp.ndarray, jnp.ndarray, Optional[jnp.ndarray]]:
+    """The decoder stack's input (B,S,D), its positions (B,S) and the
+    encoder's output (None without an encoder)."""
     if input_embeds is not None:
         x = frontends.adapter_apply(params["adapter"], input_embeds)
     else:
@@ -117,12 +113,24 @@ def hidden_states(params: Pytree, tokens: Optional[jnp.ndarray],
     enc_out = None
     if cfg.encoder_layers > 0:
         assert enc_feats is not None, "enc-dec model requires enc_feats"
-        enc_out = encode(params, enc_feats, cfg, impl=impl, remat=remat)
+        enc_out = encode(params, enc_feats, cfg, remat=remat)
+    return x, pos, enc_out
 
+
+def hidden_states(params: Pytree, tokens: Optional[jnp.ndarray],
+                  cfg: ModelConfig, *,
+                  input_embeds: Optional[jnp.ndarray] = None,
+                  enc_feats: Optional[jnp.ndarray] = None,
+                  remat: str = "none", constrain=None,
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray, MoEStats]:
+    """Final-norm hidden states (B,S,D) (pre-unembed), the moe aux loss and
+    the moe dispatch's row counts."""
+    x, pos, enc_out = _inputs(params, tokens, cfg, input_embeds, enc_feats,
+                              remat)
     if constrain is not None:
         x = constrain(x)
     x, aux, stats = transformer.stack_apply(params["stack"], x, cfg, pos,
-                                            enc_out=enc_out, impl=impl, remat=remat,
+                                            enc_out=enc_out, remat=remat,
                                             constrain=constrain)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     if constrain is not None:
@@ -133,11 +141,11 @@ def hidden_states(params: Pytree, tokens: Optional[jnp.ndarray],
 def forward(params: Pytree, tokens: Optional[jnp.ndarray], cfg: ModelConfig, *,
             input_embeds: Optional[jnp.ndarray] = None,
             enc_feats: Optional[jnp.ndarray] = None,
-            impl: str = "xla", remat: str = "none", constrain=None,
+            remat: str = "none", constrain=None,
             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (logits (B,S,V), moe_aux_loss)."""
     x, aux, _ = hidden_states(params, tokens, cfg, input_embeds=input_embeds,
-                              enc_feats=enc_feats, impl=impl, remat=remat,
+                              enc_feats=enc_feats, remat=remat,
                               constrain=constrain)
     head = params["unembed"] if "unembed" in params else params["embed"]
     logits = unembed(head, x)
@@ -197,27 +205,25 @@ def chunked_softmax_xent(x: jnp.ndarray, table: jnp.ndarray,
 
 
 def loss_fn(params: Pytree, batch: Dict[str, jnp.ndarray], cfg: ModelConfig, *,
-            impl: str = "xla", remat: str = "none", constrain=None,
+            remat: str = "none", constrain=None,
             ) -> jnp.ndarray:
     """Next-token cross-entropy (+ MoE aux). batch keys: tokens|input_embeds,
     labels, and enc_feats for enc-dec archs."""
-    return loss_and_stats(params, batch, cfg, impl=impl, remat=remat,
+    return loss_and_stats(params, batch, cfg, remat=remat,
                           constrain=constrain)[0]
 
 
 def loss_and_stats(params: Pytree, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
-                   *, impl: str = "xla", remat: str = "none", constrain=None,
+                   *, remat: str = "none", constrain=None,
                    ) -> Tuple[jnp.ndarray, MoEStats]:
     """``loss_fn``'s loss, and the row counts of its MoE dispatches."""
     labels = batch["labels"]
     x, aux, stats = hidden_states(params, batch.get("tokens"), cfg,
                                   input_embeds=batch.get("input_embeds"),
                                   enc_feats=batch.get("enc_feats"),
-                                  impl=impl, remat=remat, constrain=constrain)
+                                  remat=remat, constrain=constrain)
     head = params["unembed"] if "unembed" in params else params["embed"]
-    if padded_vocab_size(cfg) >= CHUNKED_XENT_VOCAB \
-            and not os.environ.get("REPRO_NAIVE_LOSS") \
-            and not os.environ.get("REPRO_DENSE_XENT"):
+    if padded_vocab_size(cfg) >= CHUNKED_XENT_VOCAB:
         nll = chunked_softmax_xent(x, head["table"], labels, cfg.vocab_size)
     else:
         logits = unembed(head, x)
@@ -230,17 +236,11 @@ def loss_and_stats(params: Pytree, batch: Dict[str, jnp.ndarray], cfg: ModelConf
 
 def _dense_xent(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
     """Per-token nll (B,S) fp32 from whole (B,S,V) logits."""
-    if os.environ.get("REPRO_NAIVE_LOSS"):
-        # the pre-iteration-1 formulation kept for §Perf A/B measurement:
-        # take_along_axis over the vocab axis forces GSPMD to materialize
-        # gathered fp32 logits
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        return -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
     # Cross-entropy in logsumexp + select-reduce form: every op is
     # elementwise or a reduction along vocab, so GSPMD keeps the logits
     # vocab-sharded end-to-end (partial reductions + a scalar-ish
-    # all-reduce) instead of all-gathering a (B,S,V) fp32 tensor for the
-    # take_along_axis gather. See EXPERIMENTS.md §Perf iteration 1.
+    # all-reduce) instead of all-gathering a (B,S,V) fp32 tensor for a
+    # take_along_axis gather.
     logits_f = logits.astype(jnp.float32)
     m = jax.lax.stop_gradient(jnp.max(logits_f, axis=-1, keepdims=True))
     lse = jnp.log(jnp.sum(jnp.exp(logits_f - m), axis=-1)) + m[..., 0]
@@ -257,7 +257,7 @@ def _dense_xent(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
 def prefill(params: Pytree, tokens: Optional[jnp.ndarray], cfg: ModelConfig,
             max_len: int, *, enc_feats: Optional[jnp.ndarray] = None,
             input_embeds: Optional[jnp.ndarray] = None,
-            impl: str = "xla", remat: str = "none",
+            remat: str = "none",
             ) -> Tuple[jnp.ndarray, Pytree]:
     """Process a prompt batch and build the decode state.
 
@@ -265,28 +265,15 @@ def prefill(params: Pytree, tokens: Optional[jnp.ndarray], cfg: ModelConfig,
     Returns (last-token logits (B, V), decode state with cache filled and
     length = S) — the serving prefill step.
     """
-    if input_embeds is not None:
-        x = frontends.adapter_apply(params["adapter"], input_embeds)
-    else:
-        x = embed(params["embed"], tokens)
-    if cfg.attention is not None and cfg.attention.rope_style == "none" \
-            and cfg.encoder_layers > 0:
-        x = x + sinusoidal_positions(x.shape[1], cfg.d_model)[None].astype(x.dtype)
-    b, s = x.shape[:2]
-    pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-
-    enc_out = None
-    if cfg.encoder_layers > 0:
-        assert enc_feats is not None, "enc-dec model requires enc_feats"
-        enc_out = encode(params, enc_feats, cfg, impl=impl, remat=remat)
-
+    x, pos, enc_out = _inputs(params, tokens, cfg, input_embeds, enc_feats,
+                              remat)
     x, cache, _ = transformer.stack_prefill(params["stack"], x, cfg, pos,
                                             max_len, enc_out=enc_out,
-                                            impl=impl, remat=remat)
+                                            remat=remat)
     x = rmsnorm(params["final_norm"], x[:, -1:, :], cfg.norm_eps)
     head = params["unembed"] if "unembed" in params else params["embed"]
     logits = mask_pad_logits(unembed(head, x)[:, 0, :], cfg)
-    state = {"cache": cache, "length": jnp.full((), s, jnp.int32)}
+    state = {"cache": cache, "length": jnp.full((), pos.shape[1], jnp.int32)}
     return logits, state
 
 

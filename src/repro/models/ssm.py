@@ -2,8 +2,8 @@
 
 Chunked SSD algorithm: within-chunk term is a (masked) attention-like dense
 matmul; across chunks a small recurrence over per-chunk states. This is the
-pure-jnp reference/train path; ``repro.kernels.ssd_scan`` provides the
-Pallas TPU kernel for the same math.
+path training and prefill take; ``repro.kernels.ssd_scan`` is a
+standalone Pallas kernel of the same math that no model calls.
 
 Layout follows the Mamba2 paper: d_inner = expand*d_model split into heads of
 size P=head_dim; per-head scalar decay a_t = exp(dt*A); B/C shared across
@@ -213,41 +213,11 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, a_log: jnp.ndarray,
     return y, final
 
 
-def ssm_apply(params: Params, x: jnp.ndarray, d_model: int, cfg: SSMConfig,
-              impl: str = "xla", constrain=None) -> jnp.ndarray:
-    """Full-sequence Mamba2 block. x: (B, S, D) -> (B, S, D)."""
-    dims = ssm_dims(d_model, cfg)
-    d_in, nh, ds, ng = dims["d_inner"], dims["n_heads"], dims["d_state"], dims["n_groups"]
-    bsz, s, _ = x.shape
-
-    proj = x @ params["w_in"]
-    z, xbc, dt = _split_proj(proj, d_model, cfg)
-    xbc = _causal_conv(xbc, params["conv_w"], params["conv_b"])
-    xs, B, C = jnp.split(xbc, [d_in, d_in + ng * ds], axis=-1)
-
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
-    xs = xs.reshape(bsz, s, nh, cfg.head_dim)
-    B = B.reshape(bsz, s, ng, ds)
-    C = C.reshape(bsz, s, ng, ds)
-
-    chunk = min(cfg.chunk, s)
-    if impl == "pallas":
-        from repro.kernels import ops as kops
-        y, _ = kops.ssd_scan(xs, dt, params["a_log"], B, C, chunk=chunk)
-    else:
-        y, _ = ssd_chunked(xs, dt, params["a_log"], B, C, chunk,
-                           constrain=constrain)
-    y = y + xs.astype(jnp.float32) * params["d_skip"][:, None]
-    y = y.reshape(bsz, s, d_in).astype(x.dtype)
-
-    y = rmsnorm(params["gate_norm"], y * jax.nn.silu(z))
-    return y @ params["w_out"]
-
-
-def ssm_prefill(params: Params, x: jnp.ndarray, d_model: int, cfg: SSMConfig,
-                impl: str = "xla") -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """Full-sequence Mamba2 that also emits the decode cache
-    (conv tail = last conv_width-1 *raw* xbc rows, and the final SSD state)."""
+def _ssm_body(params: Params, x: jnp.ndarray, d_model: int, cfg: SSMConfig,
+              constrain=None,
+              ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Full-sequence Mamba2 block: projection, conv, SSD scan and gate.
+    Returns (out (B, S, D), the raw pre-conv xbc rows, final SSD state)."""
     dims = ssm_dims(d_model, cfg)
     d_in, nh, ds, ng = dims["d_inner"], dims["n_heads"], dims["d_state"], dims["n_groups"]
     bsz, s, _ = x.shape
@@ -262,21 +232,29 @@ def ssm_prefill(params: Params, x: jnp.ndarray, d_model: int, cfg: SSMConfig,
     B = B.reshape(bsz, s, ng, ds)
     C = C.reshape(bsz, s, ng, ds)
 
-    chunk = min(cfg.chunk, s)
-    if impl == "pallas":
-        from repro.kernels import ops as kops
-        y, final = kops.ssd_scan(xs, dt, params["a_log"], B, C, chunk=chunk)
-    else:
-        y, final = ssd_chunked(xs, dt, params["a_log"], B, C, chunk)
+    y, final = ssd_chunked(xs, dt, params["a_log"], B, C, min(cfg.chunk, s),
+                           constrain=constrain)
     y = y + xs.astype(jnp.float32) * params["d_skip"][:, None]
     y = y.reshape(bsz, s, d_in).astype(x.dtype)
     y = rmsnorm(params["gate_norm"], y * jax.nn.silu(z))
-    out = y @ params["w_out"]
+    return y @ params["w_out"], xbc_raw, final
 
+
+def ssm_apply(params: Params, x: jnp.ndarray, d_model: int, cfg: SSMConfig,
+              constrain=None) -> jnp.ndarray:
+    """Full-sequence Mamba2 block. x: (B, S, D) -> (B, S, D)."""
+    return _ssm_body(params, x, d_model, cfg, constrain)[0]
+
+
+def ssm_prefill(params: Params, x: jnp.ndarray, d_model: int, cfg: SSMConfig,
+                ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """Full-sequence Mamba2 that also emits the decode cache
+    (conv tail = last conv_width-1 *raw* xbc rows, and the final SSD state)."""
+    out, xbc_raw, final = _ssm_body(params, x, d_model, cfg)
     # conv tail: last W-1 raw xbc rows (zero-padded on the left if s < W-1)
     w1 = cfg.conv_width - 1
     pad = jnp.pad(xbc_raw, ((0, 0), (w1, 0), (0, 0)))
-    tail = jax.lax.dynamic_slice_in_dim(pad, s, w1, axis=1)
+    tail = jax.lax.dynamic_slice_in_dim(pad, x.shape[1], w1, axis=1)
     return out, {"conv": tail.astype(x.dtype), "state": final}
 
 

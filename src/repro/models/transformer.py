@@ -12,7 +12,7 @@ runtime maps logical names -> mesh axes (see runtime/sharding.py).
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -86,42 +86,57 @@ def _layer_axes(cfg: ModelConfig, idx: int, *, cross: bool = False) -> Pytree:
     return ax
 
 
-def _layer_apply(p: Params, x: jnp.ndarray, cfg: ModelConfig, idx: int,
-                 positions: jnp.ndarray, *, enc_out: Optional[jnp.ndarray] = None,
-                 causal: bool = True, impl: str = "xla", constrain=None,
-                 ) -> Tuple[jnp.ndarray, jnp.ndarray, moe_mod.MoEStats]:
-    """Pre-norm residual layer. Returns (x, moe_aux_loss, moe stats)."""
-    kind = cfg.layer_kind(idx)
+def layer_window(cfg: ModelConfig, j: int) -> int:
+    """Lookback of attention sub-layer ``j``, 0 for none: the global layers
+    of a local/global pattern see everything, every other layer sees the
+    config's sliding window."""
+    acfg = cfg.attention
+    if acfg.local_global != (0, 0) and cfg.layer_is_global_attn(j):
+        return 0
+    return acfg.sliding_window
+
+
+def layer_apply(p: Params, h: jnp.ndarray, cfg: ModelConfig, j: int,
+                mixer: Callable[[Params, jnp.ndarray], Tuple[jnp.ndarray, Any]],
+                *, enc_out: Optional[jnp.ndarray] = None, constrain=None,
+                ) -> Tuple[jnp.ndarray, jnp.ndarray, moe_mod.MoEStats, Any]:
+    """Pre-norm residual sub-layer ``j`` of a group, for training, prefill
+    and decode alike: norm1 -> mixer -> residual -> cross-attention (where
+    the layer has it) -> norm2 -> dense or MoE FFN -> residual.
+
+    ``mixer(params, x) -> (out, extra)`` is the stack's attention or SSM
+    call; ``extra`` (a cache, or None) is handed back untouched.
+    Returns (h, moe aux loss, moe stats, extra)."""
     aux = jnp.zeros((), jnp.float32)
     stats = moe_mod.MoEStats.zeros()
-    h = rmsnorm(p["norm1"], x, cfg.norm_eps)
-    if kind == "attn":
-        acfg = cfg.attention
-        if not causal:
-            acfg = attn.AttentionConfig(**{**acfg.__dict__, "causal": False})
-        window = None
-        if acfg.local_global != (0, 0):
-            window = 0 if cfg.layer_is_global_attn(idx) else acfg.sliding_window
-        h = attn.attention_apply(p["mixer"], h, acfg, positions,
-                                 window_override=window, impl=impl)
-    else:
-        h = ssm_mod.ssm_apply(p["mixer"], h, cfg.d_model, cfg.ssm, impl=impl,
-                              constrain=constrain)
-    x = x + h
+    out, extra = mixer(p["mixer"], rmsnorm(p["norm1"], h, cfg.norm_eps))
+    h = h + out
     if "cross" in p:
-        h = rmsnorm(p["norm_cross"], x, cfg.norm_eps)
-        h = attn.attention_apply(p["cross"], h, cfg.attention, positions,
-                                 kv_source=enc_out, impl="xla")
-        x = x + h
+        x = rmsnorm(p["norm_cross"], h, cfg.norm_eps)
+        h = h + attn.cross_attention(p["cross"], x, enc_out, cfg.attention)
     if "ffn" in p:
-        h = rmsnorm(p["norm2"], x, cfg.norm_eps)
-        if cfg.layer_is_moe(idx):
-            h, aux, stats = moe_mod.moe_layer(p["ffn"], h, cfg.moe, cfg.act,
-                                              constrain=constrain)
+        x = rmsnorm(p["norm2"], h, cfg.norm_eps)
+        if cfg.layer_is_moe(j):
+            out, aux, stats = moe_mod.moe_layer(p["ffn"], x, cfg.moe, cfg.act,
+                                                constrain=constrain)
         else:
-            h = mlp_apply(p["ffn"], h, cfg.act)
-        x = x + h
-    return x, aux, stats
+            out = mlp_apply(p["ffn"], x, cfg.act)
+        h = h + out
+    return h, aux, stats, extra
+
+
+_REMAT_POLICIES = {
+    "full": jax.checkpoint_policies.nothing_saveable,
+    "dots": jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
+}
+
+
+def _remat(group_body: Callable, remat: str) -> Callable:
+    """``group_body`` checkpointed under the ``remat`` policy ("full" or
+    "dots"; any other name, "none" among them, leaves it as it is)."""
+    policy = _REMAT_POLICIES.get(remat)
+    return group_body if policy is None else jax.checkpoint(group_body,
+                                                            policy=policy)
 
 
 # ==========================================================================
@@ -153,8 +168,7 @@ def stack_axes(cfg: ModelConfig, *, cross: bool = False) -> Pytree:
 
 def stack_apply(params: Params, x: jnp.ndarray, cfg: ModelConfig,
                 positions: jnp.ndarray, *, enc_out: Optional[jnp.ndarray] = None,
-                causal: bool = True, impl: str = "xla", remat: str = "none",
-                constrain=None,
+                causal: bool = True, remat: str = "none", constrain=None,
                 ) -> Tuple[jnp.ndarray, jnp.ndarray, moe_mod.MoEStats]:
     """Returns (x, moe aux loss, moe stats summed over layers).
 
@@ -163,28 +177,30 @@ def stack_apply(params: Params, x: jnp.ndarray, cfg: ModelConfig,
     period = cfg.layer_period
     _count_moe_layers(cfg, x)
 
+    def mixer(j):
+        if cfg.layer_kind(j) == "ssm":
+            return lambda p, x: (ssm_mod.ssm_apply(p, x, cfg.d_model, cfg.ssm,
+                                                   constrain=constrain), None)
+        acfg = cfg.attention
+        if not causal:
+            acfg = attn.AttentionConfig(**{**acfg.__dict__, "causal": False})
+        return lambda p, x: (attn.attention_apply(
+            p, x, acfg, positions, window=layer_window(cfg, j)), None)
+
     def group_body(carry, gparams):
         h, aux, stats = carry
         for j in range(period):
-            h, aux_j, stats_j = _layer_apply(gparams[f"sub{j}"], h, cfg, j,
-                                             positions, enc_out=enc_out,
-                                             causal=causal, impl=impl,
-                                             constrain=constrain)
+            h, aux_j, stats_j, _ = layer_apply(gparams[f"sub{j}"], h, cfg, j,
+                                               mixer(j), enc_out=enc_out,
+                                               constrain=constrain)
             aux, stats = aux + aux_j, stats.plus(stats_j)
         if constrain is not None:
             h = constrain(h)
         return (h, aux, stats), None
 
-    if remat == "full":
-        group_body = jax.checkpoint(group_body,
-                                    policy=jax.checkpoint_policies.nothing_saveable)
-    elif remat == "dots":
-        group_body = jax.checkpoint(
-            group_body, policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
-
     (x, aux, stats), _ = jax.lax.scan(
-        group_body, (x, jnp.zeros((), jnp.float32), moe_mod.MoEStats.zeros()),
-        params)
+        _remat(group_body, remat),
+        (x, jnp.zeros((), jnp.float32), moe_mod.MoEStats.zeros()), params)
     return x, aux, stats
 
 
@@ -207,12 +223,8 @@ def _count_moe_layers(cfg: ModelConfig, x: jnp.ndarray) -> None:
 def _cache_len(cfg: ModelConfig, j: int, max_len: int) -> int:
     """Slots of attention sub-layer ``j``'s cache: sliding-window layers
     keep only ``window`` (a ring buffer)."""
-    acfg = cfg.attention
-    if acfg.local_global != (0, 0) and not cfg.layer_is_global_attn(j):
-        return min(max_len, acfg.sliding_window)
-    if acfg.sliding_window > 0 and acfg.local_global == (0, 0):
-        return min(max_len, acfg.sliding_window)
-    return max_len
+    window = layer_window(cfg, j)
+    return min(max_len, window) if window > 0 else max_len
 
 
 def stack_init_cache(cfg: ModelConfig, batch: int, max_len: int, *,
@@ -251,8 +263,7 @@ def cache_axes(cfg: ModelConfig) -> Pytree:
 
 def stack_prefill(params: Params, x: jnp.ndarray, cfg: ModelConfig,
                   positions: jnp.ndarray, max_len: int, *,
-                  enc_out: Optional[jnp.ndarray] = None, impl: str = "xla",
-                  remat: str = "none",
+                  enc_out: Optional[jnp.ndarray] = None, remat: str = "none",
                   ) -> Tuple[jnp.ndarray, Pytree, jnp.ndarray]:
     """Full-sequence pass that also builds the decode cache.
 
@@ -263,50 +274,23 @@ def stack_prefill(params: Params, x: jnp.ndarray, cfg: ModelConfig,
     period = cfg.layer_period
     _count_moe_layers(cfg, x)
 
+    def mixer(j):
+        if cfg.layer_kind(j) == "ssm":
+            return lambda p, x: ssm_mod.ssm_prefill(p, x, cfg.d_model, cfg.ssm)
+        return lambda p, x: attn.attention_prefill(
+            p, x, cfg.attention, positions, _cache_len(cfg, j, max_len),
+            window=layer_window(cfg, j))
+
     def group_body(carry, gparams):
         h, aux = carry
         gcache = {}
         for j in range(period):
-            p = gparams[f"sub{j}"]
-            kind = cfg.layer_kind(j)
-            hin = rmsnorm(p["norm1"], h, cfg.norm_eps)
-            if kind == "attn":
-                acfg = cfg.attention
-                window = None
-                if acfg.local_global != (0, 0):
-                    window = 0 if cfg.layer_is_global_attn(j) else acfg.sliding_window
-                out, c = attn.attention_prefill(p["mixer"], hin, acfg, positions,
-                                                _cache_len(cfg, j, max_len),
-                                                window_override=window, impl=impl)
-            else:
-                out, c = ssm_mod.ssm_prefill(p["mixer"], hin, cfg.d_model,
-                                             cfg.ssm, impl=impl)
-            h = h + out
-            if "cross" in p:
-                hin = rmsnorm(p["norm_cross"], h, cfg.norm_eps)
-                out = attn.attention_apply(p["cross"], hin, cfg.attention,
-                                           positions, kv_source=enc_out,
-                                           impl="xla")
-                h = h + out
-            if "ffn" in p:
-                hin = rmsnorm(p["norm2"], h, cfg.norm_eps)
-                if cfg.layer_is_moe(j):
-                    out, aux_j, _ = moe_mod.moe_layer(p["ffn"], hin, cfg.moe, cfg.act)
-                    aux = aux + aux_j
-                else:
-                    out = mlp_apply(p["ffn"], hin, cfg.act)
-                h = h + out
-            gcache[f"sub{j}"] = c
+            h, aux_j, _, gcache[f"sub{j}"] = layer_apply(
+                gparams[f"sub{j}"], h, cfg, j, mixer(j), enc_out=enc_out)
+            aux = aux + aux_j
         return (h, aux), gcache
 
-    if remat == "full":
-        group_body = jax.checkpoint(group_body,
-                                    policy=jax.checkpoint_policies.nothing_saveable)
-    elif remat == "dots":
-        group_body = jax.checkpoint(
-            group_body, policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
-
-    (x, aux), cache = jax.lax.scan(group_body,
+    (x, aux), cache = jax.lax.scan(_remat(group_body, remat),
                                    (x, jnp.zeros((), jnp.float32)), params)
     return x, cache, aux
 
@@ -331,41 +315,28 @@ def stack_decode_step(params: Params, cache: Pytree, x: jnp.ndarray,
     telemetry.count("decode.cache_layers_copied", n_groups * (period - n_attn))
     _count_moe_layers(cfg, x)
 
+    def mixer(j, layer, c):
+        if cfg.layer_kind(j) == "attn":
+            return lambda p, x: attn.attention_decode_step(
+                p, x, c, layer, cache_len, cfg.attention,
+                window=layer_window(cfg, j))
+
+        def ssm_step(p, x):
+            row = jax.tree.map(lambda t: jax.lax.dynamic_index_in_dim(
+                t, layer, 0, keepdims=False), c)
+            out, row = ssm_mod.ssm_decode_step(p, x, row, cfg.d_model, cfg.ssm)
+            return out, jax.tree.map(lambda t, r: jax.lax.dynamic_update_index_in_dim(
+                t, r, layer, 0), c, row)
+        return ssm_step
+
     def group_body(carry, scanned):
         h, cache = carry
         gparams, layer = scanned
         cache = dict(cache)
         for j in range(period):
-            p, c = gparams[f"sub{j}"], cache[f"sub{j}"]
-            hin = rmsnorm(p["norm1"], h, cfg.norm_eps)
-            if cfg.layer_kind(j) == "attn":
-                acfg = cfg.attention
-                window = None
-                if acfg.local_global != (0, 0):
-                    window = 0 if cfg.layer_is_global_attn(j) else acfg.sliding_window
-                out, c = attn.attention_decode_step(p["mixer"], hin, c, layer,
-                                                    cache_len, acfg,
-                                                    window_override=window)
-            else:
-                row = jax.tree.map(lambda t: jax.lax.dynamic_index_in_dim(
-                    t, layer, 0, keepdims=False), c)
-                out, row = ssm_mod.ssm_decode_step(p["mixer"], hin, row,
-                                                   cfg.d_model, cfg.ssm)
-                c = jax.tree.map(lambda t, r: jax.lax.dynamic_update_index_in_dim(
-                    t, r, layer, 0), c, row)
-            h = h + out
-            if "cross" in p:
-                hin = rmsnorm(p["norm_cross"], h, cfg.norm_eps)
-                h = h + attn.attention_apply(p["cross"], hin, cfg.attention,
-                                             None, kv_source=enc_out)
-            if "ffn" in p:
-                hin = rmsnorm(p["norm2"], h, cfg.norm_eps)
-                if cfg.layer_is_moe(j):
-                    out, _, _ = moe_mod.moe_layer(p["ffn"], hin, cfg.moe, cfg.act)
-                else:
-                    out = mlp_apply(p["ffn"], hin, cfg.act)
-                h = h + out
-            cache[f"sub{j}"] = c
+            h, _, _, cache[f"sub{j}"] = layer_apply(
+                gparams[f"sub{j}"], h, cfg, j, mixer(j, layer, cache[f"sub{j}"]),
+                enc_out=enc_out)
         return (h, cache), None
 
     (x, cache), _ = jax.lax.scan(group_body, (x, cache),

@@ -24,17 +24,14 @@ from repro.models.model import decode_step, prefill
 Pytree = Any
 
 
-def make_serve_step(cfg: ModelConfig, *, sample: str = "greedy",
-                    ) -> Callable:
+def make_serve_step(cfg: ModelConfig) -> Callable:
     """serve_step(params, state, tokens (B,), [enc_out]) ->
-    (next_tokens (B,), logits (B,V), new state)."""
+    (greedy next_tokens (B,), logits (B,V), new state)."""
 
     def serve_step(params: Pytree, state: Pytree, tokens: jnp.ndarray,
                    enc_out: Optional[jnp.ndarray] = None):
         logits, new_state = decode_step(params, state, tokens, cfg,
                                         enc_out=enc_out)
-        if sample != "greedy":
-            raise ValueError(sample)
         with jax.named_scope("decode_sample"):
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return nxt, logits, new_state
@@ -42,15 +39,14 @@ def make_serve_step(cfg: ModelConfig, *, sample: str = "greedy",
     return serve_step
 
 
-def make_prefill_step(cfg: ModelConfig, max_len: int, *, impl: str = "xla",
-                      ) -> Callable:
+def make_prefill_step(cfg: ModelConfig, max_len: int) -> Callable:
     """prefill_step(params, tokens (B,S), [enc_feats]) ->
     (first sampled token (B,), decode state)."""
 
     def prefill_step(params: Pytree, tokens: jnp.ndarray,
                      enc_feats: Optional[jnp.ndarray] = None):
         logits, state = prefill(params, tokens, cfg, max_len,
-                                enc_feats=enc_feats, impl=impl)
+                                enc_feats=enc_feats)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), state
 
     return prefill_step

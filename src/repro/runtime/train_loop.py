@@ -28,7 +28,7 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ArchBundle, ModelConfig
+from repro.configs.base import ArchBundle, ModelConfig, TrainConfig
 from repro.models.model import init_params, loss_and_stats
 from repro.models.moe import MoEStats
 from repro.optim.adamw import AdamWState, adamw_init, adamw_update
@@ -57,8 +57,27 @@ def train_state_init(key, cfg: ModelConfig, bundle: ArchBundle) -> TrainState:
     return TrainState(params, opt, jnp.zeros((), jnp.int32), ef)
 
 
-def make_train_step(cfg: ModelConfig, bundle: ArchBundle, *, impl: str = "xla",
-                    constrain=None,
+def _update(tc: TrainConfig, state: TrainState, grads: Pytree,
+            ) -> Tuple[TrainState, jnp.ndarray, jnp.ndarray]:
+    """One optimizer step on ``grads``: compression (with its error
+    feedback), the warmup-cosine rate and AdamW. Returns (the new state,
+    the gradient norm, the rate)."""
+    ef = state.ef
+    if tc.compression != "none":
+        sent, new_cs = compress_decompress(
+            grads, CompressionState(ef), scheme=tc.compression)
+        grads, ef = sent, new_cs.error
+    lr = warmup_cosine(state.step, peak_lr=tc.lr,
+                       warmup_steps=tc.warmup_steps,
+                       total_steps=tc.total_steps)
+    params, opt, gnorm = adamw_update(
+        grads, state.opt, state.params, lr=lr, beta1=tc.beta1,
+        beta2=tc.beta2, weight_decay=tc.weight_decay,
+        grad_clip=tc.grad_clip)
+    return TrainState(params, opt, state.step + 1, ef), gnorm, lr
+
+
+def make_train_step(cfg: ModelConfig, bundle: ArchBundle, *, constrain=None,
                     ) -> Callable[[TrainState, Dict[str, jnp.ndarray]],
                                   Tuple[TrainState, Dict[str, jnp.ndarray]]]:
     """constrain: optional residual-stream sharding hook (sequence-parallel
@@ -69,20 +88,8 @@ def make_train_step(cfg: ModelConfig, bundle: ArchBundle, *, impl: str = "xla",
     def train_step(state: TrainState, batch: Dict[str, jnp.ndarray],
                    ) -> Tuple[TrainState, Dict[str, jnp.ndarray]]:
         (loss, moe), grads = jax.value_and_grad(loss_and_stats, has_aux=True)(
-            state.params, batch, cfg, impl=impl, remat=remat, constrain=constrain)
-        ef = state.ef
-        if tc.compression != "none":
-            sent, new_cs = compress_decompress(
-                grads, CompressionState(ef), scheme=tc.compression)
-            grads, ef = sent, new_cs.error
-        lr = warmup_cosine(state.step, peak_lr=tc.lr,
-                           warmup_steps=tc.warmup_steps,
-                           total_steps=tc.total_steps)
-        params, opt, gnorm = adamw_update(
-            grads, state.opt, state.params, lr=lr, beta1=tc.beta1,
-            beta2=tc.beta2, weight_decay=tc.weight_decay,
-            grad_clip=tc.grad_clip)
-        new_state = TrainState(params, opt, state.step + 1, ef)
+            state.params, batch, cfg, remat=remat, constrain=constrain)
+        new_state, gnorm, lr = _update(tc, state, grads)
         metrics = {"loss": loss, "grad_norm": gnorm, "lr": lr, "moe": moe}
         return new_state, metrics
 
@@ -115,21 +122,21 @@ def _fold(acc: GrainAcc, loss, moe: MoEStats, grads) -> GrainAcc:
         moe=moe if acc.moe is None else acc.moe.plus(moe))
 
 
-def make_grain_step(cfg: ModelConfig, bundle: ArchBundle, *, impl: str = "xla",
+def make_grain_step(cfg: ModelConfig, bundle: ArchBundle, *,
                     jit: bool = True) -> Callable:
     remat = bundle.mesh.remat
 
     def grain_step(params: Pytree, acc: GrainAcc,
                    grain: Dict[str, jnp.ndarray]) -> GrainAcc:
         (loss, moe), grads = jax.value_and_grad(loss_and_stats, has_aux=True)(
-            params, grain, cfg, impl=impl, remat=remat)
+            params, grain, cfg, remat=remat)
         return _fold(acc, loss, moe, grads)
 
     return jax.jit(grain_step) if jit else grain_step
 
 
 def make_grain_accumulate(cfg: ModelConfig, bundle: ArchBundle, *,
-                          impl: str = "xla", jit: bool = True) -> Callable:
+                          jit: bool = True) -> Callable:
     """(params, acc, grains[G, ...]) -> acc after folding all G grains.
 
     Semantically identical to calling ``grain_step`` G times in stacking
@@ -142,7 +149,7 @@ def make_grain_accumulate(cfg: ModelConfig, bundle: ArchBundle, *,
                          grains: Dict[str, jnp.ndarray]) -> GrainAcc:
         def body(carry: GrainAcc, grain: Dict[str, jnp.ndarray]):
             (loss, moe), grads = jax.value_and_grad(loss_and_stats, has_aux=True)(
-                params, grain, cfg, impl=impl, remat=remat)
+                params, grain, cfg, remat=remat)
             return _fold(carry, loss, moe, grads), None
 
         if acc.moe is None:   # the scan's carry keeps one structure
@@ -159,16 +166,14 @@ def make_grain_accumulate(cfg: ModelConfig, bundle: ArchBundle, *,
 _GRAIN_ACC_CACHE: Dict[Any, Callable] = {}
 
 
-def grain_accumulate_cached(cfg: ModelConfig, bundle: ArchBundle, *,
-                            impl: str = "xla") -> Callable:
+def grain_accumulate_cached(cfg: ModelConfig, bundle: ArchBundle) -> Callable:
     """Module-level cache of jitted grain-accumulate functions, keyed by the
-    frozen (cfg, bundle, impl) triple: every driver with the same config
-    shares one traced program."""
-    key = (cfg, bundle, impl)
+    frozen (cfg, bundle) pair: every driver with the same config shares one
+    traced program."""
+    key = (cfg, bundle)
     fn = _GRAIN_ACC_CACHE.get(key)
     if fn is None:
-        fn = _GRAIN_ACC_CACHE[key] = make_grain_accumulate(cfg, bundle,
-                                                           impl=impl)
+        fn = _GRAIN_ACC_CACHE[key] = make_grain_accumulate(cfg, bundle)
     return fn
 
 
@@ -184,21 +189,10 @@ def make_apply_step(cfg: ModelConfig, bundle: ArchBundle, *,
                    ) -> Tuple[TrainState, Dict[str, jnp.ndarray]]:
         denom = jnp.maximum(total_grains.astype(jnp.float32), 1.0)
         grads = jax.tree.map(lambda g: g / denom, acc.grads)
-        ef = state.ef
-        if tc.compression != "none":
-            sent, new_cs = compress_decompress(
-                grads, CompressionState(ef), scheme=tc.compression)
-            grads, ef = sent, new_cs.error
-        lr = warmup_cosine(state.step, peak_lr=tc.lr,
-                           warmup_steps=tc.warmup_steps,
-                           total_steps=tc.total_steps)
-        params, opt, gnorm = adamw_update(
-            grads, state.opt, state.params, lr=lr, beta1=tc.beta1,
-            beta2=tc.beta2, weight_decay=tc.weight_decay,
-            grad_clip=tc.grad_clip)
+        new_state, gnorm, lr = _update(tc, state, grads)
         metrics = {"loss": acc.loss_sum / denom, "grad_norm": gnorm, "lr": lr,
                    "moe": acc.moe}
-        return TrainState(params, opt, state.step + 1, ef), metrics
+        return new_state, metrics
 
     # donating acc lets its fp32 grads back the new fp32 moments
     return jax.jit(apply_step, donate_argnums=(1,)) if jit else apply_step

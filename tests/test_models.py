@@ -490,8 +490,10 @@ def test_gemma3_local_global_pattern():
 
 def test_chunked_xent_matches_dense():
     """Memory-lean vocab-chunked cross-entropy == dense loss, value + grad."""
-    import os
-    from repro.models.model import chunked_softmax_xent, hidden_states
+    from repro.models.layers import unembed
+    from repro.models.model import (
+        _dense_xent, chunked_softmax_xent, hidden_states, mask_pad_logits,
+    )
 
     cfg = dataclasses.replace(get_reduced("granite-3-8b"), vocab_size=1234,
                               dtype="float32")
@@ -500,11 +502,9 @@ def test_chunked_xent_matches_dense():
              "labels": jax.random.randint(KEY, (2, 12), 1, cfg.vocab_size)}
 
     def f_dense(p):
-        os.environ["REPRO_DENSE_XENT"] = "1"
-        try:
-            return loss_fn(p, batch, cfg)
-        finally:
-            del os.environ["REPRO_DENSE_XENT"]
+        x, aux, _ = hidden_states(p, batch["tokens"], cfg)
+        logits = mask_pad_logits(unembed(p["embed"], x), cfg)
+        return jnp.mean(_dense_xent(logits, batch["labels"])) + aux
 
     def f_chunk(p):
         x, aux, _ = hidden_states(p, batch["tokens"], cfg)
